@@ -5,12 +5,16 @@ speed factors calibrated so the device/cloud end-to-end latency ratios land
 in the regime of the paper's Figures 6-9 (the paper itself emulates the
 network conditions; we additionally emulate tier speeds since only one
 machine is available).  Benchmark DBs are cached on disk under
-``results/benchdb`` so repeated runs skip Steps 2-3, like the real tool.
+``results/benchdb``, one file per model and device, so repeated runs skip
+Steps 2-3, like the real tool.
 """
 
 from __future__ import annotations
 
 import os
+import re
+
+import jax
 
 from repro.core import (AnalyticProvider, Link, NetworkModel, QueryEngine,
                         Resource, Scission, TimingProvider, benchmark_model,
@@ -108,17 +112,27 @@ def fleet_engine(n_per_tier: int = 9, n_blocks: int = 32,
                        input_bytes=input_bytes)
 
 
+def cache_path(model_name: str) -> str:
+    """The DB file of ``model_name`` measured on this process's device:
+    keyed by ``platform`` and ``device_kind``, so timings taken on one
+    device are never read back as another's."""
+    dev = jax.devices()[0]
+    kind = re.sub(r"[^A-Za-z0-9.-]+", "_", dev.device_kind)
+    return os.path.join(CACHE_DIR, f"{model_name}.{dev.platform}.{kind}.json")
+
+
 def benchmark_cached(scission: Scission, model_name: str,
                      batch_sizes: tuple[int, ...] = (1,)):
     """Steps 1-3 with a disk cache (the paper's offline benchmarking).
 
-    The cache is reused only when it covers the requested resources AND
-    batch sizes; otherwise the model is re-benchmarked with the union of
-    cached and requested batches, so a batched scenario upgrades the cached
-    DB in place (old scalar caches load as batch-1 profiles).
+    The cache file is per device (:func:`cache_path`) and is reused only
+    when it covers the requested resources AND batch sizes; otherwise the
+    model is re-benchmarked with the union of cached and requested batches,
+    so a batched scenario upgrades the cached DB in place (old scalar
+    caches load as batch-1 profiles).
     """
     os.makedirs(CACHE_DIR, exist_ok=True)
-    path = os.path.join(CACHE_DIR, f"{model_name}.json")
+    path = cache_path(model_name)
     want_batches = set(batch_sizes) | {1}
     if os.path.exists(path):
         db = scission.restore(path)

@@ -19,6 +19,8 @@ def main() -> None:
     args = ap.parse_args()
     quick = not args.full
 
+    from repro.launch.cache import enable_compile_cache
+    enable_compile_cache()
     from . import (bench_autotune, bench_elastic, bench_overhead,
                    bench_partitions, bench_query, bench_roofline, bench_zoo)
 

@@ -18,8 +18,9 @@ fills the other), scratch buffers are single-buffered, and SMEM operands
     vmem = 2 * (sum of input blocks + sum of output blocks) + scratch
 
 The per-kernel functions below mirror the BlockSpecs in ``kernels/*.py``
-one for one — including the ``min(block, dim)`` clamping the kernels apply
-— so the analyzer and the kernels cannot drift apart silently (the unit
+one for one — head-major ``(B, H, S, ·)`` operands whose squeezed batch and
+head dims count as 1, and the ``min(block, dim)`` clamping the kernels
+apply — so the analyzer and the kernels cannot drift apart silently (the unit
 tests assert the mirrored shapes against the kernel sources' specs).
 """
 
@@ -84,12 +85,13 @@ def _flash_attention_footprint(params: dict, args: Sequence,
     bq = min(int(params.get("block_q", 128)), int(Sq))
     bk = min(int(params.get("block_k", 128)), int(Sk))
     blocks = {
-        "q": (1, bq, 1, hd), "k": (1, bk, 1, hd), "v": (1, bk, 1, hd),
-        "o": (1, bq, 1, hd),
+        "q": (1, 1, bq, hd), "k": (1, 1, bk, hd), "v": (1, 1, bk, hd),
+        "o": (1, 1, bq, hd),
     }
     in_b = sum(_nbytes(blocks[n], q.dtype) for n in ("q", "k", "v"))
     out_b = _nbytes(blocks["o"], q.dtype)
-    scratch = _nbytes((bq,), np.float32) * 2 + _nbytes((bq, hd), np.float32)
+    scratch = _nbytes((bq, 1), np.float32) * 2 \
+        + _nbytes((bq, hd), np.float32)
     return KernelFootprint("flash_attention", dict(params),
                            DOUBLE_BUFFER * in_b, DOUBLE_BUFFER * out_b,
                            scratch, blocks)
@@ -113,13 +115,14 @@ def _decode_attention_footprint(params: dict, args: Sequence,
                          "length (k/v argument or options['cache_len'])")
     bk = min(int(params.get("block_k", 256)), Smax)
     blocks = {
-        "q": (1, 1, G, hd), "k": (1, bk, 1, hd), "v": (1, bk, 1, hd),
+        "q": (1, 1, G, hd), "k": (1, 1, bk, hd), "v": (1, 1, bk, hd),
         "o": (1, 1, G, hd),
     }
     # the lengths vector lives in SMEM — excluded from the VMEM budget
     in_b = sum(_nbytes(blocks[n], q.dtype) for n in ("q", "k", "v"))
     out_b = _nbytes(blocks["o"], q.dtype)
-    scratch = _nbytes((G,), np.float32) * 2 + _nbytes((G, hd), np.float32)
+    scratch = _nbytes((G, 1), np.float32) * 2 \
+        + _nbytes((G, hd), np.float32)
     return KernelFootprint("decode_attention", dict(params),
                            DOUBLE_BUFFER * in_b, DOUBLE_BUFFER * out_b,
                            scratch, blocks)
@@ -133,8 +136,8 @@ def _ssd_scan_footprint(params: dict, args: Sequence,
         else int(options.get("state_dim", 16))
     L = min(int(params.get("chunk", 128)), int(S))
     blocks = {
-        "x": (1, L, 1, P), "log_a": (1, L, 1), "b": (1, L, 1, N),
-        "c": (1, L, 1, N), "y": (1, L, 1, P), "final": (1, 1, N, P),
+        "x": (1, 1, L, P), "log_a": (1, 1, L, 1), "b": (1, 1, L, N),
+        "c": (1, 1, L, N), "y": (1, 1, L, P), "final": (1, 1, N, P),
     }
     in_b = sum(_nbytes(blocks[n], x.dtype)
                for n in ("x", "log_a", "b", "c"))

@@ -41,9 +41,9 @@ def _kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
 
     @pl.when(k_start < length)
     def _compute():
-        q = q_ref[0, 0].astype(jnp.float32)                # (G, hd)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)          # (bk, hd)
-        v = v_ref[0, :, 0, :].astype(jnp.float32)          # (bk, hd)
+        q = q_ref[...].astype(jnp.float32)                 # (G, hd)
+        k = k_ref[...].astype(jnp.float32)                 # (bk, hd)
+        v = v_ref[...].astype(jnp.float32)                 # (bk, hd)
 
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
@@ -53,12 +53,12 @@ def _kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
         kpos = k_start + jax.lax.broadcasted_iota(jnp.int32, (1, bk), 1)
         s = jnp.where(kpos < length, s, NEG_INF)
 
-        m_prev = m_scr[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
-        p = jnp.exp(s - m_new[:, None])
+        m_prev = m_scr[...]                                 # (G, 1)
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        p = jnp.exp(s - m_new)
         alpha = jnp.exp(m_prev - m_new)
-        l_scr[...] = l_scr[...] * alpha + jnp.sum(p, axis=1)
-        acc_scr[...] = acc_scr[...] * alpha[:, None] + jax.lax.dot_general(
+        l_scr[...] = l_scr[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
+        acc_scr[...] = acc_scr[...] * alpha + jax.lax.dot_general(
             p, v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
         m_scr[...] = m_new
@@ -67,7 +67,7 @@ def _kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
     def _finalize():
         l = l_scr[...]
         l = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0, 0] = (acc_scr[...] / l[:, None]).astype(o_ref.dtype)
+        o_ref[...] = (acc_scr[...] / l).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("softcap", "block_k",
@@ -88,8 +88,10 @@ def decode_attention(q, k, v, lengths, *, softcap=None, block_k=256,
     G = H // Hk
     bk = min(block_k, Smax)
     Smax_p = round_up(Smax, bk)
-    k = pad_axis_to(k, 1, Smax_p)
-    v = pad_axis_to(v, 1, Smax_p)
+    # head-major (B, Hk, Smax, hd): each cache tile's last two dims are
+    # (bk, hd)
+    k = pad_axis_to(k, 1, Smax_p).transpose(0, 2, 1, 3)
+    v = pad_axis_to(v, 1, Smax_p).transpose(0, 2, 1, 3)
     nk = Smax_p // bk
     scale = 1.0 / math.sqrt(hd)
 
@@ -102,15 +104,16 @@ def decode_attention(q, k, v, lengths, *, softcap=None, block_k=256,
         grid=grid,
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),          # lengths (B,)
-            pl.BlockSpec((1, 1, G, hd), lambda b, h, ik: (b, h, 0, 0)),
-            pl.BlockSpec((1, bk, 1, hd), lambda b, h, ik: (b, ik, h, 0)),
-            pl.BlockSpec((1, bk, 1, hd), lambda b, h, ik: (b, ik, h, 0)),
+            pl.BlockSpec((None, None, G, hd), lambda b, h, ik: (b, h, 0, 0)),
+            pl.BlockSpec((None, None, bk, hd), lambda b, h, ik: (b, h, ik, 0)),
+            pl.BlockSpec((None, None, bk, hd), lambda b, h, ik: (b, h, ik, 0)),
         ],
-        out_specs=pl.BlockSpec((1, 1, G, hd), lambda b, h, ik: (b, h, 0, 0)),
+        out_specs=pl.BlockSpec((None, None, G, hd),
+                               lambda b, h, ik: (b, h, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((B, Hk, G, hd), q.dtype),
         scratch_shapes=[
-            pltpu.VMEM((G,), jnp.float32),
-            pltpu.VMEM((G,), jnp.float32),
+            pltpu.VMEM((G, 1), jnp.float32),
+            pltpu.VMEM((G, 1), jnp.float32),
             pltpu.VMEM((G, hd), jnp.float32),
         ],
         compiler_params=tpu_compiler_params(

@@ -13,8 +13,12 @@ via ``pl.when`` (block-level masking).
 Uneven sequence lengths are handled by the substrate layer: q/k/v are
 zero-padded up to the next block boundary, padded key positions are masked
 to ``-inf`` via ``kpos < k_len``, and padded query rows are sliced off the
-output.  Compiler params resolve through ``substrate.tpu_compiler_params``
-so both old (``TPUCompilerParams``) and new (``CompilerParams``) JAX work.
+output.
+
+The wrapper transposes q/k/v to head-major ``(B, H, S, hd)`` so every block
+is a ``(rows, hd)`` tile: the TPU compiler requires a block's last two dims
+to be multiples of (8, 128) or whole, which a head axis of 1 in the
+second-minor place of ``(B, S, H, hd)`` is not.
 """
 
 from __future__ import annotations
@@ -58,9 +62,9 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
 
     @pl.when(relevant)
     def _compute():
-        q = q_ref[0, :, 0, :].astype(jnp.float32)          # (bq, hd)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)          # (bk, hd)
-        v = v_ref[0, :, 0, :].astype(jnp.float32)          # (bk, hd)
+        q = q_ref[...].astype(jnp.float32)                 # (bq, hd)
+        k = k_ref[...].astype(jnp.float32)                 # (bk, hd)
+        v = v_ref[...].astype(jnp.float32)                 # (bk, hd)
 
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
@@ -76,12 +80,12 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
             mask &= qpos - kpos < window
         s = jnp.where(mask, s, NEG_INF)
 
-        m_prev = m_scr[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
-        p = jnp.exp(s - m_new[:, None])
+        m_prev = m_scr[...]                                 # (bq, 1)
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        p = jnp.exp(s - m_new)
         alpha = jnp.exp(m_prev - m_new)
-        l_scr[...] = l_scr[...] * alpha + jnp.sum(p, axis=1)
-        acc_scr[...] = acc_scr[...] * alpha[:, None] + jax.lax.dot_general(
+        l_scr[...] = l_scr[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
+        acc_scr[...] = acc_scr[...] * alpha + jax.lax.dot_general(
             p, v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
         m_scr[...] = m_new
@@ -90,7 +94,7 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
     def _finalize():
         l = l_scr[...]
         l = jnp.where(l == 0.0, 1.0, l)    # fully-masked rows -> zeros
-        o_ref[0, :, 0, :] = (acc_scr[...] / l[:, None]).astype(o_ref.dtype)
+        o_ref[...] = (acc_scr[...] / l).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "window", "softcap",
@@ -109,9 +113,10 @@ def flash_attention(q, k, v, *, causal=True, window=None, softcap=None,
     bq = min(block_q, Sq)
     bk = min(block_k, Sk)
     Sq_p, Sk_p = round_up(Sq, bq), round_up(Sk, bk)
-    q = pad_axis_to(q, 1, Sq_p)
-    k = pad_axis_to(k, 1, Sk_p)
-    v = pad_axis_to(v, 1, Sk_p)
+    # head-major (B, H, S, hd): each block's last two dims are (rows, hd)
+    q = pad_axis_to(q, 1, Sq_p).transpose(0, 2, 1, 3)
+    k = pad_axis_to(k, 1, Sk_p).transpose(0, 2, 1, 3)
+    v = pad_axis_to(v, 1, Sk_p).transpose(0, 2, 1, 3)
     nq, nk = Sq_p // bq, Sk_p // bk
     scale = 1.0 / math.sqrt(hd)
 
@@ -123,23 +128,24 @@ def flash_attention(q, k, v, *, causal=True, window=None, softcap=None,
         kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, bq, 1, hd), lambda b, h, iq, ik: (b, iq, h, 0)),
-            pl.BlockSpec((1, bk, 1, hd),
-                         lambda b, h, iq, ik, g=group: (b, ik, h // g, 0)),
-            pl.BlockSpec((1, bk, 1, hd),
-                         lambda b, h, iq, ik, g=group: (b, ik, h // g, 0)),
+            pl.BlockSpec((None, None, bq, hd),
+                         lambda b, h, iq, ik: (b, h, iq, 0)),
+            pl.BlockSpec((None, None, bk, hd),
+                         lambda b, h, iq, ik, g=group: (b, h // g, ik, 0)),
+            pl.BlockSpec((None, None, bk, hd),
+                         lambda b, h, iq, ik, g=group: (b, h // g, ik, 0)),
         ],
-        out_specs=pl.BlockSpec((1, bq, 1, hd),
-                               lambda b, h, iq, ik: (b, iq, h, 0)),
+        out_specs=pl.BlockSpec((None, None, bq, hd),
+                               lambda b, h, iq, ik: (b, h, iq, 0)),
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         scratch_shapes=[
-            pltpu.VMEM((bq,), jnp.float32),
-            pltpu.VMEM((bq,), jnp.float32),
+            pltpu.VMEM((bq, 1), jnp.float32),
+            pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, hd), jnp.float32),
         ],
         compiler_params=tpu_compiler_params(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
-    )(q, k, v)
+    )(q, k, v).transpose(0, 2, 1, 3)
     return out[:, :Sq] if Sq_p != Sq else out

@@ -30,41 +30,48 @@ def _kernel(x_ref, a_ref, b_ref, c_ref, y_ref, fin_ref, state_scr, *,
     def _init():
         state_scr[...] = jnp.zeros_like(state_scr)
 
-    x = x_ref[0, :, 0, :].astype(jnp.float32)        # (L, P)
-    la = a_ref[0, :, 0].astype(jnp.float32)          # (L,)
-    b = b_ref[0, :, 0, :].astype(jnp.float32)        # (L, N)
-    c = c_ref[0, :, 0, :].astype(jnp.float32)        # (L, N)
+    x = x_ref[...].astype(jnp.float32)               # (L, P)
+    la_col = a_ref[...].astype(jnp.float32)          # (L, 1)
+    b = b_ref[...].astype(jnp.float32)               # (L, N)
+    c = c_ref[...].astype(jnp.float32)               # (L, N)
 
-    seg = jnp.cumsum(la)                             # (L,)
-    total = seg[-1]
+    # Prefix sums as masked reductions over (L, L): every value stays 2-D,
+    # and the sums are exact f32 adds rather than reduced-precision matmuls.
+    row = jax.lax.broadcasted_iota(jnp.int32, (L, L), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (L, L), 1)
+    causal = row >= col
+    la_row = jnp.sum(jnp.where(row == col, la_col, 0.0), axis=0,
+                     keepdims=True)                  # (1, L)
+    seg_col = jnp.sum(jnp.where(causal, la_row, 0.0), axis=1,
+                      keepdims=True)                 # (L, 1): seg_i
+    seg_row = jnp.sum(jnp.where(row <= col, la_col, 0.0), axis=0,
+                      keepdims=True)                 # (1, L): seg_j
+    total = jnp.sum(la_col, axis=0, keepdims=True)   # (1, 1)
 
     # intra-chunk: scores_ij = c_i·b_j * exp(seg_i - seg_j) for j <= i
     scores = jax.lax.dot_general(c, b, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
-    diff = seg[:, None] - seg[None, :]
-    causal = jax.lax.broadcasted_iota(jnp.int32, (L, L), 0) >= \
-        jax.lax.broadcasted_iota(jnp.int32, (L, L), 1)
-    decay = jnp.where(causal, jnp.exp(diff), 0.0)
+    decay = jnp.where(causal, jnp.exp(seg_col - seg_row), 0.0)
     y = jax.lax.dot_general(scores * decay, x, (((1,), (0,)), ((), ())),
                             preferred_element_type=jnp.float32)
 
     # inter-chunk: y += exp(seg_i) * c_i · state_in
     state_in = state_scr[...]                        # (N, P)
-    y += jnp.exp(seg)[:, None] * jax.lax.dot_general(
+    y += jnp.exp(seg_col) * jax.lax.dot_general(
         c, state_in, (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)
 
     # state update: state = state * exp(total) + Σ_j exp(total - seg_j) b_j x_jᵀ
-    w = jnp.exp(total - seg)                         # (L,)
+    w = jnp.exp(total - seg_col)                     # (L, 1)
     state_scr[...] = state_in * jnp.exp(total) + jax.lax.dot_general(
-        b * w[:, None], x, (((0,), (0,)), ((), ())),
+        b * w, x, (((0,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)
 
-    y_ref[0, :, 0, :] = y.astype(y_ref.dtype)
+    y_ref[...] = y.astype(y_ref.dtype)
 
     @pl.when(ic == nc - 1)
     def _final():
-        fin_ref[0, 0] = state_scr[...].astype(fin_ref.dtype)
+        fin_ref[...] = state_scr[...].astype(fin_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
@@ -82,10 +89,12 @@ def ssd_scan(x, log_a, b, c, *, chunk=128, interpret=False):
     N = b.shape[-1]
     L = min(chunk, S)
     S_p = round_up(S, L)
-    x = pad_axis_to(x, 1, S_p)
-    log_a = pad_axis_to(log_a, 1, S_p)
-    b = pad_axis_to(b, 1, S_p)
-    c = pad_axis_to(c, 1, S_p)
+    # head-major (B, H, S, ·): each block's last two dims are (L, ·), and
+    # log_a rides as an (L, 1) column
+    x = pad_axis_to(x, 1, S_p).transpose(0, 2, 1, 3)
+    log_a = pad_axis_to(log_a, 1, S_p).transpose(0, 2, 1)[..., None]
+    b = pad_axis_to(b, 1, S_p).transpose(0, 2, 1, 3)
+    c = pad_axis_to(c, 1, S_p).transpose(0, 2, 1, 3)
     nc = S_p // L
 
     grid = (B, H, nc)
@@ -94,14 +103,14 @@ def ssd_scan(x, log_a, b, c, *, chunk=128, interpret=False):
         kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, L, 1, P), lambda bi, h, ic: (bi, ic, h, 0)),
-            pl.BlockSpec((1, L, 1), lambda bi, h, ic: (bi, ic, h)),
-            pl.BlockSpec((1, L, 1, N), lambda bi, h, ic: (bi, ic, h, 0)),
-            pl.BlockSpec((1, L, 1, N), lambda bi, h, ic: (bi, ic, h, 0)),
+            pl.BlockSpec((None, None, L, P), lambda bi, h, ic: (bi, h, ic, 0)),
+            pl.BlockSpec((None, None, L, 1), lambda bi, h, ic: (bi, h, ic, 0)),
+            pl.BlockSpec((None, None, L, N), lambda bi, h, ic: (bi, h, ic, 0)),
+            pl.BlockSpec((None, None, L, N), lambda bi, h, ic: (bi, h, ic, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, L, 1, P), lambda bi, h, ic: (bi, ic, h, 0)),
-            pl.BlockSpec((1, 1, N, P), lambda bi, h, ic: (bi, h, 0, 0)),
+            pl.BlockSpec((None, None, L, P), lambda bi, h, ic: (bi, h, ic, 0)),
+            pl.BlockSpec((None, None, N, P), lambda bi, h, ic: (bi, h, 0, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct(x.shape, x.dtype),
@@ -112,4 +121,5 @@ def ssd_scan(x, log_a, b, c, *, chunk=128, interpret=False):
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(x, log_a, b, c)
+    y = y.transpose(0, 2, 1, 3)
     return (y[:, :S] if S_p != S else y), fin

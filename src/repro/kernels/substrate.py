@@ -1,20 +1,15 @@
-"""Version-portable substrate under the Pallas TPU kernels.
+"""Substrate under the Pallas TPU kernels.
 
 All three kernels (``flash_attention``, ``decode_attention``, ``ssd_scan``)
 and the measurement layer (``core/bench.py``, ``launch/dryrun.py``) route
-through this module instead of touching version-sensitive JAX surfaces
-directly.  It provides:
+through this module.  It provides:
 
-* **Compiler-params compat shim** — JAX renamed
-  ``pltpu.TPUCompilerParams`` to ``pltpu.CompilerParams`` across releases;
-  :func:`tpu_compiler_params` resolves whichever the installed JAX exposes
-  (and silently drops keyword arguments the resolved class does not accept),
-  so the same kernel source compiles on both old and new JAX.
-* **Cost-analysis normalizer** — ``jit(...).lower().compile()
-  .cost_analysis()`` returns a plain dict on some JAX versions and a
-  list-of-dicts (one per computation) on others;
-  :func:`normalize_cost_analysis` collapses either form into one flat
-  ``{metric: float}`` dict so providers can always call ``.get``.
+* **Compiler params** — :func:`tpu_compiler_params` builds
+  ``pltpu.CompilerParams`` and raises on a field the installed class does
+  not take, so a kernel never loses e.g. ``dimension_semantics`` unseen.
+* **Cost analysis** — :func:`normalize_cost_analysis` keeps the numeric
+  entries of the dict ``compile().cost_analysis()`` returns, so providers
+  can always call ``.get``.
 * **Pad-and-mask helpers** — :func:`round_up` / :func:`pad_axis_to` let the
   kernels accept sequence lengths that are not multiples of the block size:
   inputs are zero-padded up to the next block boundary, padded key/value
@@ -30,100 +25,43 @@ directly.  It provides:
 
 from __future__ import annotations
 
+import dataclasses
 import json
 import math
+import sys
 import time
 from dataclasses import dataclass, field, asdict
 from typing import Any, Callable
 
 import jax
 import jax.numpy as jnp
+from jax.experimental.pallas import tpu as pltpu
 
 # ---------------------------------------------------------------------------
-# compiler-params compat shim
+# compiler params
 # ---------------------------------------------------------------------------
 
-_COMPILER_PARAMS_NAMES = ("CompilerParams", "TPUCompilerParams")
 
-
-def resolve_compiler_params_cls():
-    """Return the TPU compiler-params class of the installed JAX, or None.
-
-    Newer JAX exposes ``pltpu.CompilerParams``; older releases call it
-    ``pltpu.TPUCompilerParams``.  Returns ``None`` when the Pallas TPU
-    extension is unavailable entirely (pure-CPU builds) — ``pallas_call``
-    accepts ``compiler_params=None``.
-    """
-    try:
-        from jax.experimental.pallas import tpu as pltpu
-    except ImportError:  # pragma: no cover - pallas always present here
-        return None
-    for name in _COMPILER_PARAMS_NAMES:
-        cls = getattr(pltpu, name, None)
-        if cls is not None:
-            return cls
-    return None
-
-
-def _accepted_fields(cls) -> set[str]:
-    fields = getattr(cls, "__dataclass_fields__", None)
-    if fields:
-        return set(fields)
-    init = getattr(cls, "__init__", None)
-    code = getattr(init, "__code__", None)
-    if code is not None:
-        return set(code.co_varnames[1:code.co_argcount + code.co_kwonlyargcount])
-    return set()
-
-
-def tpu_compiler_params(**kwargs):
-    """Instantiate TPU compiler params portably.
-
-    Unknown keyword arguments (fields added/removed between JAX versions)
-    are dropped rather than raising, so kernels can always request e.g.
-    ``dimension_semantics`` without guarding on the JAX version.
-    """
-    cls = resolve_compiler_params_cls()
-    if cls is None:
-        return None
-    accepted = _accepted_fields(cls)
-    if accepted:
-        kwargs = {k: v for k, v in kwargs.items() if k in accepted}
-    try:
-        return cls(**kwargs)
-    except TypeError:
-        return None
+def tpu_compiler_params(**kwargs) -> pltpu.CompilerParams:
+    """``pltpu.CompilerParams(**kwargs)``, refusing unknown fields by name."""
+    known = {f.name for f in dataclasses.fields(pltpu.CompilerParams)}
+    unknown = sorted(set(kwargs) - known)
+    if unknown:
+        raise TypeError(f"pltpu.CompilerParams takes no field(s) {unknown}")
+    return pltpu.CompilerParams(**kwargs)
 
 
 # ---------------------------------------------------------------------------
-# cost-analysis normalizer
+# cost analysis
 # ---------------------------------------------------------------------------
 
-def normalize_cost_analysis(cost: Any) -> dict[str, float]:
-    """Collapse any ``compile().cost_analysis()`` return into one flat dict.
-
-    Handles the three shapes seen across JAX versions/backends:
-
-    * ``dict``                       -> copied through;
-    * ``list``/``tuple`` of dicts    -> numeric entries summed per key
-      (one dict per computation; summing is the per-module total);
-    * ``None`` / anything else      -> ``{}``.
-    """
-    if cost is None:
-        return {}
-    if isinstance(cost, dict):
-        return {k: float(v) for k, v in cost.items()
-                if isinstance(v, (int, float))}
-    if isinstance(cost, (list, tuple)):
-        out: dict[str, float] = {}
-        for entry in cost:
-            if not isinstance(entry, dict):
-                continue
-            for k, v in entry.items():
-                if isinstance(v, (int, float)):
-                    out[k] = out.get(k, 0.0) + float(v)
-        return out
-    return {}
+def normalize_cost_analysis(cost: dict[str, Any]) -> dict[str, float]:
+    """The numeric entries of a ``compile().cost_analysis()`` dict."""
+    if not isinstance(cost, dict):
+        raise TypeError(f"cost_analysis() returned {type(cost).__name__}, "
+                        "expected a dict")
+    return {k: float(v) for k, v in cost.items()
+            if isinstance(v, (int, float))}
 
 
 def compiled_costs(compiled) -> dict[str, float]:
@@ -200,6 +138,9 @@ class TuneRecord:
     # candidates statically pruned by the TPU tiling analyzer before
     # timing: json(params) -> misalignment reason (repro.analysis.tiling)
     tile_pruned: dict[str, str] = field(default_factory=dict)
+    # candidates the compiler or runtime refused:
+    # json(params) -> "ExceptionType: message"
+    refused: dict[str, str] = field(default_factory=dict)
 
     @property
     def changed_default(self) -> bool:
@@ -230,10 +171,11 @@ class KernelAutotuner:
 
     ``tune`` measures wall-clock of a jit'd candidate callable (min over
     ``runs`` after a compile warm-up) — the same measurement discipline as
-    ``TimingProvider``.  Candidates that fail to trace/compile (e.g. an
-    unsupported block shape) are skipped, which keeps sweeps safe across JAX
-    versions.  A custom ``measure`` hook replaces wall-clock timing (used by
-    unit tests and by roofline-style offline tuning).
+    ``TimingProvider``.  A candidate that fails to trace, compile or run
+    (e.g. a block shape the TPU compiler refuses) is recorded in
+    ``TuneRecord.refused`` and reported on stderr, and the sweep goes on
+    with the rest.  A custom ``measure`` hook replaces wall-clock timing
+    (used by unit tests and by roofline-style offline tuning).
     """
 
     def __init__(self, candidates: dict[str, list[dict[str, int]]] | None = None,
@@ -251,6 +193,7 @@ class KernelAutotuner:
         # resource (speed factors scale uniformly), so trial tables are
         # shared across resources; each resource still gets its own record.
         self._trials: dict[tuple[str, str], dict[str, float]] = {}
+        self._refused: dict[tuple[str, str], dict[str, str]] = {}
         # Per-resource VMEM budgets in bytes: candidates whose static
         # footprint (repro.analysis.kernel_vmem) exceeds the tuned
         # resource's budget are pruned before timing.
@@ -354,15 +297,18 @@ class KernelAutotuner:
                 tile_pruned = flagged
 
         trials = self._trials.setdefault((kernel, shape_key), {})
-        failures: dict[str, str] = {}
+        failures = self._refused.setdefault((kernel, shape_key), {})
         for params in kept:
             pkey = json.dumps(params, sort_keys=True)
-            if pkey in trials:
+            if pkey in trials or pkey in failures:
                 continue
             try:
                 trials[pkey] = self._time_candidate(factory(params), args)
-            except Exception as e:  # unsupported block shape on this version
+            except Exception as e:  # noqa: BLE001 — recorded and reported
                 failures[pkey] = f"{type(e).__name__}: {e}"
+                print(f"autotune: {kernel} {shape_key} candidate {pkey} "
+                      f"refused on {jax.default_backend()}: "
+                      f"{failures[pkey]}", file=sys.stderr)
 
         kept_keys = {json.dumps(p, sort_keys=True) for p in kept}
         admissible = {k: t for k, t in trials.items() if k in kept_keys}
@@ -382,7 +328,9 @@ class KernelAutotuner:
                          default_params=defaults,
                          default_time_s=admissible.get(dkey, float("nan")),
                          trials=admissible, pruned=pruned, vmem_limit=budget,
-                         tile_pruned=tile_pruned)
+                         tile_pruned=tile_pruned,
+                         refused={k: v for k, v in failures.items()
+                                  if k in kept_keys})
         self.records[key] = rec
         return rec
 

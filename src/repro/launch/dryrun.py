@@ -1,11 +1,9 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 """Multi-pod dry-run: lower + compile every (architecture × input shape) on
 the production meshes and extract memory/cost/collective analysis.
 
-MUST be executed as its own process (``python -m repro.launch.dryrun``) so
-the XLA_FLAGS above take effect before jax initialises its backends.
+MUST be executed as its own process (``python -m repro.launch.dryrun``):
+:func:`main` appends the 512-host-device flag to ``XLA_FLAGS``, which takes
+effect only before jax initialises its backends.
 
     python -m repro.launch.dryrun --arch gemma2-9b --shape train_4k
     python -m repro.launch.dryrun --all [--multi-pod-only|--single-pod-only]
@@ -14,6 +12,7 @@ the XLA_FLAGS above take effect before jax initialises its backends.
 
 import argparse
 import json
+import os
 import re
 import time
 import traceback
@@ -221,6 +220,9 @@ def run_cell(arch: str, shape: ShapeConfig, *, multi_pod: bool,
 
 
 def main():
+    os.environ["XLA_FLAGS"] = (
+        os.environ.get("XLA_FLAGS", "")
+        + " --xla_force_host_platform_device_count=512").strip()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default=None)
     ap.add_argument("--shape", default=None)

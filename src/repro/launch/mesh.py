@@ -1,13 +1,24 @@
-"""Production mesh construction.
+"""Mesh construction.
 
-A FUNCTION (not a module-level constant) so importing this module never
+FUNCTIONS (not module-level constants) so importing this module never
 touches jax device state — required because the dry-run must set
 XLA_FLAGS before any jax initialisation.
+
+Every mesh has ``Auto`` axes: the model code places arrays with
+``with_sharding_constraint`` (``runtime/sharding.py:constrain``), which
+takes only ``Auto`` axes, while ``jax.make_mesh`` defaults to ``Explicit``.
 """
 
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def make_mesh(shape: tuple[int, ...], axes: tuple[str, ...], devices=None):
+    """``jax.make_mesh`` with ``Auto`` axes (over ``devices`` when given)."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -15,9 +26,4 @@ def make_production_mesh(*, multi_pod: bool = False):
     axis for cross-pod data parallelism."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
-
-
-def make_smoke_mesh():
-    """Single-device mesh for CPU tests."""
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return make_mesh(shape, axes)

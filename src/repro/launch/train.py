@@ -20,6 +20,7 @@ import jax.numpy as jnp
 
 from repro.checkpoint import CheckpointManager
 from repro.data import DataConfig, SyntheticLM
+from repro.launch.cache import enable_compile_cache
 from repro.launch.steps import make_train_step
 from repro.models import build_model, get_config
 from repro.optim import AdamWConfig, cosine_with_warmup, init_state
@@ -57,6 +58,7 @@ def main():
     ap.add_argument("--ckpt-every", type=int, default=50)
     args = ap.parse_args()
 
+    enable_compile_cache()
     cfg = get_config(args.arch)
     if args.tiny:
         cfg = tiny(cfg)

@@ -25,6 +25,9 @@ import numpy as np
 from repro.core.graph import LayerGraph, LayerNode
 
 # NHWC everywhere.
+# Making this key initialises JAX's default backend as the module is
+# imported, which on a TPU host takes the chip: a process that imports this
+# module must not start child processes that need the chip.
 _KEY = [jax.random.PRNGKey(1234)]
 
 

@@ -93,8 +93,8 @@ class TestKernelVmem:
         q = np.zeros((1, 32, 2, 16), np.float32)
         fp = kernel_footprint("flash_attention",
                               {"block_q": 256, "block_k": 256}, [q])
-        assert fp.blocks["q"] == (1, 32, 1, 16)
-        assert fp.blocks["k"] == (1, 32, 1, 16)
+        assert fp.blocks["q"] == (1, 1, 32, 16)
+        assert fp.blocks["k"] == (1, 1, 32, 16)
 
     def test_ssd_footprint_hand_computed(self):
         # x (1, 64, 1, 16) f32, chunk 32, N=8 (via options):

@@ -274,7 +274,7 @@ class TestJaxprLint:
         assert lint_blocks(fuse_blocks(g)) == []
 
     def test_scn501_float64_leakage(self):
-        with jax.experimental.enable_x64():
+        with jax.enable_x64(True):
             g = _graph_of(_dense_node(
                 "f64", apply=lambda x: (x.astype(jnp.float64) * 2.0)
                 .astype(jnp.float32)))

@@ -1,4 +1,4 @@
-"""Kernel substrate layer: compat shim, cost normalizer, pad-and-mask
+"""Kernel substrate layer: compiler params, cost normalizer, pad-and-mask
 parity on uneven shapes, and the block-size autotuner."""
 
 import json
@@ -19,37 +19,30 @@ from repro.kernels.ref import (decode_attention_ref, flash_attention_ref,
                                ssd_ref)
 from repro.kernels.substrate import (KernelAutotuner, TuneRecord,
                                      normalize_cost_analysis, pad_axis_to,
-                                     resolve_compiler_params_cls, round_up,
-                                     tpu_compiler_params)
+                                     round_up, tpu_compiler_params)
 
 TOL32 = dict(rtol=2e-5, atol=2e-5)
 
 
 # ---------------------------------------------------------------------------
-# compat shim
+# compiler params
 # ---------------------------------------------------------------------------
 
 class TestCompilerParamsShim:
     def test_resolves_on_installed_jax(self):
-        """Whatever the installed JAX calls it, the shim must find it."""
         from jax.experimental.pallas import tpu as pltpu
-        cls = resolve_compiler_params_cls()
-        assert cls is not None
-        assert cls in (getattr(pltpu, "CompilerParams", None),
-                       getattr(pltpu, "TPUCompilerParams", None))
+        assert isinstance(tpu_compiler_params(), pltpu.CompilerParams)
 
     def test_constructs_with_dimension_semantics(self):
         params = tpu_compiler_params(
             dimension_semantics=("parallel", "arbitrary"))
-        assert params is not None
+        assert tuple(params.dimension_semantics) == ("parallel", "arbitrary")
 
     def test_unknown_kwargs_dropped(self):
-        params = tpu_compiler_params(
-            dimension_semantics=("parallel",),
-            kwarg_from_a_future_jax_version=42)
-        assert params is not None
-        assert not hasattr(params, "kwarg_from_a_future_jax_version") or \
-            getattr(params, "kwarg_from_a_future_jax_version", None) != 42
+        """An unknown field is refused by name, never silently dropped."""
+        with pytest.raises(TypeError, match="kwarg_from_a_future"):
+            tpu_compiler_params(dimension_semantics=("parallel",),
+                                kwarg_from_a_future_jax_version=42)
 
 
 # ---------------------------------------------------------------------------
@@ -61,16 +54,12 @@ class TestNormalizeCostAnalysis:
         got = normalize_cost_analysis({"flops": 10, "bytes accessed": 3.5})
         assert got == {"flops": 10.0, "bytes accessed": 3.5}
 
-    def test_list_of_dicts_summed(self):
-        got = normalize_cost_analysis([{"flops": 10.0, "bytes accessed": 4.0},
-                                       {"flops": 5.0}])
-        assert got == {"flops": 15.0, "bytes accessed": 4.0}
-
     def test_none_and_junk(self):
-        assert normalize_cost_analysis(None) == {}
-        assert normalize_cost_analysis("nope") == {}
-        assert normalize_cost_analysis([{"flops": 1.0}, "junk"]) == \
+        assert normalize_cost_analysis({"flops": 1.0, "note": "x"}) == \
             {"flops": 1.0}
+        for junk in (None, "nope", [{"flops": 1.0}]):
+            with pytest.raises(TypeError):
+                normalize_cost_analysis(junk)
 
     def test_real_compiled_artifact(self):
         lowered = jax.jit(lambda x: jnp.tanh(x @ x)).lower(
@@ -247,6 +236,12 @@ class TestKernelAutotuner:
         tuner = KernelAutotuner(measure=lambda fn, args: 0.5)
         rec = tuner.tune("ssd_scan", factory, (jnp.zeros((1, 4)),))
         assert rec.params == {"chunk": 64}
+        # every refusal is recorded with its reason, none skipped unseen
+        assert set(rec.refused) == set(rec.trials) ^ {
+            json.dumps(p, sort_keys=True)
+            for p in tuner.candidates["ssd_scan"]}
+        assert all("unsupported block shape" in why
+                   for why in rec.refused.values())
 
     def test_json_roundtrip(self):
         tuner = KernelAutotuner(measure=_fake_measure({"chunk": 32}))

@@ -85,10 +85,11 @@ class TestCollectiveParser:
 
 SPMD_SCRIPT = r"""
 import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
+                           + " --xla_force_host_platform_device_count=8")
 import jax, jax.numpy as jnp
 import numpy as np
-from repro.launch.mesh import make_production_mesh
+from repro.launch.mesh import make_mesh
 from repro.launch.steps import make_train_step, input_specs, shardings_for, rules_for
 from repro.models import build_model, get_config
 from repro.optim import AdamWConfig, init_state
@@ -102,7 +103,7 @@ cfg = get_config("granite-8b").replace(
     vocab=256, remat=False, q_chunk=32, loss_seq_chunk=None)
 model = build_model(cfg)
 shape = ShapeConfig("t", seq_len=32, global_batch=8, kind="train")
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_mesh((2, 4), ("data", "model"))
 rules = single_pod_rules()
 
 params = model.init(jax.random.PRNGKey(0))
@@ -130,10 +131,13 @@ print("SPMD_OK", l0, l1)
 def test_spmd_matches_single_device(tmp_path):
     """Run the 8-device SPMD parity check in a subprocess (device count must
     be set before jax init)."""
+    import os
+    import pathlib
     import subprocess
     import sys
+    repo = pathlib.Path(__file__).resolve().parents[1]
     p = subprocess.run(
         [sys.executable, "-c", SPMD_SCRIPT], capture_output=True, text=True,
-        env={**__import__("os").environ, "PYTHONPATH": "src"},
-        cwd="/root/repo", timeout=600)
+        env={**os.environ, "PYTHONPATH": str(repo / "src")},
+        cwd=repo, timeout=600)
     assert "SPMD_OK" in p.stdout, p.stdout + p.stderr
