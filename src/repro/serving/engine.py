@@ -22,6 +22,23 @@ On a cloud-edge deployment the *placement* of the two phases comes from the
 Scission query engine (e.g. prefill on the pod, decode on the regional
 slice, or the paper's device/edge/cloud split for CNNs); here the engine
 runs single-host but the phase boundary and cache handoff are the same.
+
+Each tick's phases are ``jax.profiler.TraceAnnotation`` spans, nested on
+the calling thread, with the layer's counters as the spans' arguments
+(event stats in a profiler trace; nothing is formatted when no profiler
+session is active):
+
+* ``serving.step`` (``queued``, ``active`` at entry): one :meth:`step`;
+* ``serving.admit`` (``rows``, ``rids``: space-joined request ids, as a
+  comma would split the annotation's arguments): an admission that took
+  requests from the queue;
+* ``serving.prefill`` (``rows``, ``width``, ``bucket``, ``real_tokens``:
+  the prompt positions the rows hold, the rest of ``width x bucket`` being
+  padding): dispatch of one prefill;
+* ``serving.scatter`` (``rows``): the prefilled rows' copy into the pool;
+* ``serving.decode`` (``rows``: active slots): dispatch of a decode step;
+* ``serving.readback``: the host's wait for the decoded tokens;
+* ``serving.bookkeep`` (``finished``): the per-slot loop after it.
 """
 
 from __future__ import annotations
@@ -31,6 +48,7 @@ import time
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core.partition import PartitionConfig
 from repro.launch.steps import make_decode_step, make_prefill_step
@@ -146,14 +164,21 @@ class ServingEngine:
             jax.block_until_ready(out[0])
         return self
 
+    def step(self) -> list[Request]:
+        """One engine tick: admit what the queue holds and the pool has
+        slots for, then one decode step over the active slots.  Returns
+        the requests that finished in it."""
+        with TraceAnnotation("serving.step", queued=len(self.queue),
+                             active=len(self.active)):
+            self._admit()
+            return self._decode_step() if self.active else []
+
     def run(self, max_steps: int = 10_000) -> list[Request]:
         finished: list[Request] = []
         steps = 0
         t0 = time.perf_counter()
         while (self.queue or self.active) and steps < max_steps:
-            self._admit()
-            if self.active:
-                self._decode_step(finished)
+            finished += self.step()
             steps += 1
         waits = [r.queue_wait_s for r in finished
                  if r.queue_wait_s is not None]
@@ -185,11 +210,13 @@ class ServingEngine:
             batch.append((req, slot))
         if not batch:
             return
-        if self.prompt_buckets is None:
-            for req, slot in batch:
-                self._admit_exact(req, slot)
-            return
-        self._admit_bucketed(batch)
+        with TraceAnnotation("serving.admit", rows=len(batch),
+                             rids=" ".join(str(r.rid) for r, _ in batch)):
+            if self.prompt_buckets is None:
+                for req, slot in batch:
+                    self._admit_exact(req, slot)
+            else:
+                self._admit_bucketed(batch)
 
     def _admit_exact(self, req: Request, slot: int) -> None:
         """Legacy per-request prefill (recurrent-state models): one jit
@@ -197,8 +224,11 @@ class ServingEngine:
         token taken from the prefill logits."""
         prompt = jnp.asarray(req.prompt, jnp.int32)[None, :]
         single = self.model.init_cache(batch=1, max_len=self.max_len)
-        logits, single = self._prefill(self.params, single,
-                                       {"tokens": prompt})
+        n = len(req.prompt)
+        with TraceAnnotation("serving.prefill", rows=1, width=1, bucket=n,
+                             real_tokens=n):
+            logits, single = self._prefill(self.params, single,
+                                           {"tokens": prompt})
         tok = int(jnp.argmax(logits[0, -1]))
         req.tokens.append(tok)
         req.admitted_at = time.perf_counter()
@@ -226,8 +256,12 @@ class ServingEngine:
             toks = np.zeros((self.width, bucket), np.int32)
             for j, (req, _) in enumerate(batch):
                 toks[j, :len(req.prompt) - 1] = req.prompt[:-1]
-            _, cache = self._prefill(self.params, self._scratch_cache(),
-                                     {"tokens": jnp.asarray(toks)})
+            with TraceAnnotation(
+                    "serving.prefill", rows=len(batch), width=self.width,
+                    bucket=bucket,
+                    real_tokens=sum(len(r.prompt) - 1 for r, _ in batch)):
+                _, cache = self._prefill(self.params, self._scratch_cache(),
+                                         {"tokens": jnp.asarray(toks)})
             self._scatter_rows(cache, rows=list(range(len(batch))),
                                slots=[slot for _, slot in batch])
         for req, slot in batch:
@@ -247,7 +281,9 @@ class ServingEngine:
         def write(dst, src):
             return dst.at[:, slots_ix].set(src[:, rows_ix])
 
-        self.pool.cache = jax.tree.map(write, self.pool.cache, src_cache)
+        with TraceAnnotation("serving.scatter", rows=len(rows)):
+            self.pool.cache = jax.tree.map(write, self.pool.cache,
+                                           src_cache)
 
     def _write_slot(self, single_cache, slot: int) -> None:
         def write(dst, src):
@@ -256,31 +292,39 @@ class ServingEngine:
             # scalar-state tuples where it is axis 1 as well.
             return dst.at[:, slot:slot + 1].set(src)
 
-        self.pool.cache = jax.tree.map(write, self.pool.cache, single_cache)
+        with TraceAnnotation("serving.scatter", rows=1):
+            self.pool.cache = jax.tree.map(write, self.pool.cache,
+                                           single_cache)
 
-    def _decode_step(self, finished: list[Request]) -> None:
+    def _decode_step(self) -> list[Request]:
         # ragged continuous batching: per-slot cache lengths drive per-row
         # positions, write offsets and attention masks
-        cache_len = jnp.asarray(self.pool.lengths, jnp.int32)
-        tok = jnp.asarray(self._next_tok)
-        next_tok, logits, self.pool.cache = self._decode(
-            self.params, self.pool.cache, tok, cache_len)
-        nxt = np.asarray(next_tok)
-        now = time.perf_counter()
-        for slot, req in list(self.active.items()):
-            t = int(nxt[slot, 0])
-            req.tokens.append(t)
-            if req.first_token_at is None:
-                req.first_token_at = now
-            self.pool.lengths[slot] += 1
-            limit = (len(req.tokens) >= req.max_new_tokens
-                     or (self.eos_id is not None and t == self.eos_id)
-                     or self.pool.lengths[slot] >= self.max_len - 1)
-            if limit:
-                req.done = True
-                req.finished_at = now
-                finished.append(req)
-                del self.active[slot]
-                self.pool.release(slot)
-            else:
-                self._next_tok[slot, 0] = t
+        with TraceAnnotation("serving.decode", rows=len(self.active)):
+            cache_len = jnp.asarray(self.pool.lengths, jnp.int32)
+            tok = jnp.asarray(self._next_tok)
+            next_tok, logits, self.pool.cache = self._decode(
+                self.params, self.pool.cache, tok, cache_len)
+        with TraceAnnotation("serving.readback"):
+            nxt = np.asarray(next_tok)
+        finished: list[Request] = []
+        with TraceAnnotation("serving.bookkeep") as span:
+            now = time.perf_counter()
+            for slot, req in list(self.active.items()):
+                t = int(nxt[slot, 0])
+                req.tokens.append(t)
+                if req.first_token_at is None:
+                    req.first_token_at = now
+                self.pool.lengths[slot] += 1
+                limit = (len(req.tokens) >= req.max_new_tokens
+                         or (self.eos_id is not None and t == self.eos_id)
+                         or self.pool.lengths[slot] >= self.max_len - 1)
+                if limit:
+                    req.done = True
+                    req.finished_at = now
+                    finished.append(req)
+                    del self.active[slot]
+                    self.pool.release(slot)
+                else:
+                    self._next_tok[slot, 0] = t
+            span.set_metadata(finished=len(finished))
+        return finished
