@@ -242,8 +242,7 @@ def phase_serving(arch: str = "granite-moe-3b-a800m", *, tiny: bool = False,
     n = len(prompt) - 1
     toks = np.zeros((width, bucket_for(n, eng.prompt_buckets)), np.int32)
     toks[0, :n] = prompt[:-1]
-    _, cache = eng._prefill(eng.params, eng._scratch_cache(),
-                            {"tokens": jnp.asarray(toks)})
+    _, cache = eng._prefill(eng.params, {"tokens": jnp.asarray(toks)})
     last = np.zeros((width, 1), np.int32)
     last[0, 0] = prompt[-1]
     lengths = np.zeros((width,), np.int32)

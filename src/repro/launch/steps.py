@@ -53,6 +53,21 @@ def make_prefill_step(model, rules: AxisRules | None, mesh: Mesh | None):
     return prefill_step
 
 
+def make_fresh_prefill_step(model, max_len: int):
+    """``prefill_step(params, batch)``: :func:`make_prefill_step` into a
+    zeros cache of the batch's rows and ``max_len`` positions, built inside
+    the same program.  The caller holds no scratch cache, and the zeroing
+    is part of the prefill's device time."""
+    step = make_prefill_step(model, None, None)
+
+    def prefill_step(params, batch):
+        cache = model.init_cache(batch=batch["tokens"].shape[0],
+                                 max_len=max_len)
+        return step(params, cache, batch)
+
+    return prefill_step
+
+
 def make_decode_step(model, rules: AxisRules | None, mesh: Mesh | None):
     def decode_step(params, cache, token, cache_len):
         with use_rules(rules, mesh):

@@ -20,7 +20,7 @@ Layered package (split out of the old single-file engine):
 from .engine import (KVCachePool, Request, ServingEngine, ServingStats,
                      simulate_pipeline_throughput)
 from .metrics import PlaneReport, mean, percentile
-from .queues import PROMPT_BUCKETS, StageQueue, bucket_for
+from .queues import PROMPT_BUCKETS, StageQueue, bucket_for, row_bucket
 from .requests import (Arrival, arrivals_to_requests, bursty_diurnal_trace,
                        empirical_rate, poisson_trace)
 from .router import ExecutorBackend, RoutedRequest, Router, VirtualBackend
@@ -30,5 +30,6 @@ __all__ = [
     "PlaneReport", "Request", "RoutedRequest", "Router", "ServingEngine",
     "ServingStats", "StageQueue", "VirtualBackend", "arrivals_to_requests",
     "bucket_for", "bursty_diurnal_trace", "empirical_rate", "mean",
-    "percentile", "poisson_trace", "simulate_pipeline_throughput",
+    "percentile", "poisson_trace", "row_bucket",
+    "simulate_pipeline_throughput",
 ]

@@ -15,8 +15,11 @@ The engine owns:
 * a :class:`KVCachePool` (slot-per-sequence paging at sequence granularity),
 * a request queue with admission up to the batch width,
 * the jitted prefill/decode steps — same-tick admissions share **one**
-  prefill over a padded prompt bucket (compiles bounded by the fixed
-  bucket set), instead of one jit call + fresh batch-1 cache per request.
+  prefill over a padded prompt bucket and a power-of-two row bucket that
+  covers them (:func:`~repro.serving.queues.row_bucket`), into a zeros
+  cache built inside the prefill program; compiles are bounded by row
+  buckets x length buckets, instead of one jit call + fresh batch-1 cache
+  per request.
 
 On a cloud-edge deployment the *placement* of the two phases comes from the
 Scission query engine (e.g. prefill on the pod, decode on the regional
@@ -32,9 +35,10 @@ session is active):
 * ``serving.admit`` (``rows``, ``rids``: space-joined request ids, as a
   comma would split the annotation's arguments): an admission that took
   requests from the queue;
-* ``serving.prefill`` (``rows``, ``width``, ``bucket``, ``real_tokens``:
-  the prompt positions the rows hold, the rest of ``width x bucket`` being
-  padding): dispatch of one prefill;
+* ``serving.prefill`` (``rows``: the real rows; ``width``: the rows
+  computed, the row bucket; ``bucket``; ``real_tokens``: the prompt
+  positions the rows hold, the rest of ``width x bucket`` being padding
+  that the chip computes): dispatch of one prefill;
 * ``serving.scatter`` (``rows``): the prefilled rows' copy into the pool;
 * ``serving.decode`` (``rows``: active slots): dispatch of a decode step;
 * ``serving.readback``: the host's wait for the decoded tokens;
@@ -51,10 +55,10 @@ import numpy as np
 from jax.profiler import TraceAnnotation
 
 from repro.core.partition import PartitionConfig
-from repro.launch.steps import make_decode_step, make_prefill_step
+from repro.launch.steps import make_decode_step, make_fresh_prefill_step
 
 from .metrics import ServingStats, mean, percentile
-from .queues import KVCachePool, PROMPT_BUCKETS, bucket_for
+from .queues import KVCachePool, PROMPT_BUCKETS, bucket_for, row_bucket
 from .requests import Request
 from .sim import simulate_pipeline_throughput
 
@@ -101,7 +105,7 @@ class ServingEngine:
         self.max_len = max_len
         self.eos_id = eos_id
         self.pool = KVCachePool(model, width, max_len)
-        self._prefill = jax.jit(make_prefill_step(model, None, None))
+        self._prefill = jax.jit(make_fresh_prefill_step(model, max_len))
         self._decode = jax.jit(make_decode_step(model, None, None))
         if prompt_buckets == "auto":
             kinds = set(getattr(self.cfg, "group_kinds", ()) or ())
@@ -113,9 +117,6 @@ class ServingEngine:
             prompt_buckets = tuple(sorted(
                 {b for b in prompt_buckets if b < max_len} | {max_len}))
         self.prompt_buckets = prompt_buckets
-        # zeros scratch cache for the batched bucket prefill (prefill is
-        # functional, so one allocation serves every admission tick)
-        self._scratch = None
         self.queue: list[Request] = []
         self.active: dict[int, Request] = {}       # slot -> request
         self._next_tok = np.zeros((width, 1), np.int32)
@@ -133,9 +134,11 @@ class ServingEngine:
     def warmup(self) -> "ServingEngine":
         """Pre-compile the decode step and the prefill bucket(s) the queued
         requests will need (the smallest bucket when the queue is empty),
-        so the next :meth:`run`'s :class:`ServingStats` measure serving,
-        not jit compilation.  Idempotent; results are discarded — no
-        engine state changes."""
+        each at every row bucket up to the width, so the next
+        :meth:`run`'s :class:`ServingStats` measure serving, not jit
+        compilation.  The prefills are compiled, not run: running every row
+        bucket at every length would add their device time to set-up.  The
+        decode step runs once.  Idempotent; no engine state changes."""
         dec = self._decode(self.params, self.pool.cache,
                            jnp.asarray(self._next_tok),
                            jnp.asarray(self.pool.lengths, jnp.int32))
@@ -143,25 +146,22 @@ class ServingEngine:
         if self.prompt_buckets is None:
             # exact-path compiles key on prompt length; warm each distinct
             # length present in the queue
-            lens = sorted({len(r.prompt) for r in self.queue
-                           if len(r.prompt) > 1})
-            for L in lens:
-                single = self.model.init_cache(batch=1, max_len=self.max_len)
-                out = self._prefill(self.params, single,
-                                    {"tokens": jnp.zeros((1, L), jnp.int32)})
-                jax.block_until_ready(out[0])
-            return self
-        if self.queue:
-            buckets = sorted({bucket_for(max(len(r.prompt) - 1, 1),
-                                         self.prompt_buckets)
-                              for r in self.queue if len(r.prompt) > 1})
+            shapes = [(1, L) for L in sorted({len(r.prompt)
+                                              for r in self.queue
+                                              if len(r.prompt) > 1})]
         else:
-            buckets = [min(self.prompt_buckets)]
-        for b in buckets:
-            out = self._prefill(self.params, self._scratch_cache(),
-                                {"tokens": jnp.zeros((self.width, b),
-                                                     jnp.int32)})
-            jax.block_until_ready(out[0])
+            if self.queue:
+                buckets = sorted({bucket_for(max(len(r.prompt) - 1, 1),
+                                             self.prompt_buckets)
+                                  for r in self.queue if len(r.prompt) > 1})
+            else:
+                buckets = [min(self.prompt_buckets)]
+            rows = sorted({row_bucket(k, self.width)
+                           for k in range(1, self.width + 1)})
+            shapes = [(r, b) for b in buckets for r in rows]
+        for shape in shapes:
+            self._prefill.lower(self.params, {"tokens": jax.ShapeDtypeStruct(
+                shape, jnp.int32)}).compile()
         return self
 
     def step(self) -> list[Request]:
@@ -196,12 +196,6 @@ class ServingEngine:
         return self.stats.requests_per_s
 
     # -- internals --------------------------------------------------------------
-    def _scratch_cache(self):
-        if self._scratch is None:
-            self._scratch = self.model.init_cache(batch=self.width,
-                                                  max_len=self.max_len)
-        return self._scratch
-
     def _admit(self) -> None:
         batch: list[tuple[Request, int]] = []
         while self.queue and self.pool.free:
@@ -223,12 +217,10 @@ class ServingEngine:
         call per distinct prompt length, fresh batch-1 cache, the first
         token taken from the prefill logits."""
         prompt = jnp.asarray(req.prompt, jnp.int32)[None, :]
-        single = self.model.init_cache(batch=1, max_len=self.max_len)
         n = len(req.prompt)
         with TraceAnnotation("serving.prefill", rows=1, width=1, bucket=n,
                              real_tokens=n):
-            logits, single = self._prefill(self.params, single,
-                                           {"tokens": prompt})
+            logits, single = self._prefill(self.params, {"tokens": prompt})
         tok = int(jnp.argmax(logits[0, -1]))
         req.tokens.append(tok)
         req.admitted_at = time.perf_counter()
@@ -240,8 +232,11 @@ class ServingEngine:
 
     def _admit_bucketed(self, batch: list[tuple[Request, int]]) -> None:
         """One prefill for every same-tick admission: prompts minus their
-        last token are right-padded into the smallest covering bucket
-        (fixed batch width, so compiles are bounded by the bucket count),
+        last token are right-padded into the smallest covering length
+        bucket, over the :func:`row_bucket` of the admitted rows (so
+        compiles are bounded by row buckets x length buckets, and the chip
+        computes the padding rows up to the next power of two, not up to
+        the width: ``serving.prefill``'s ``width`` is the rows computed),
         the resulting cache rows are scattered into the admitted slots,
         and the *last* prompt token becomes each slot's first decode input
         — the next decode step then produces the first generated token
@@ -253,14 +248,15 @@ class ServingEngine:
         pre = max(len(req.prompt) - 1 for req, _ in batch)
         if pre > 0:
             bucket = bucket_for(pre, self.prompt_buckets)
-            toks = np.zeros((self.width, bucket), np.int32)
+            rows = row_bucket(len(batch), self.width)
+            toks = np.zeros((rows, bucket), np.int32)
             for j, (req, _) in enumerate(batch):
                 toks[j, :len(req.prompt) - 1] = req.prompt[:-1]
             with TraceAnnotation(
-                    "serving.prefill", rows=len(batch), width=self.width,
+                    "serving.prefill", rows=len(batch), width=rows,
                     bucket=bucket,
                     real_tokens=sum(len(r.prompt) - 1 for r, _ in batch)):
-                _, cache = self._prefill(self.params, self._scratch_cache(),
+                _, cache = self._prefill(self.params,
                                          {"tokens": jnp.asarray(toks)})
             self._scatter_rows(cache, rows=list(range(len(batch))),
                                slots=[slot for _, slot in batch])
@@ -272,7 +268,7 @@ class ServingEngine:
 
     def _scatter_rows(self, src_cache, rows: list[int],
                       slots: list[int]) -> None:
-        """Copy batch rows ``rows`` of a width-batch cache into pool slots
+        """Copy batch rows ``rows`` of a prefill's cache into pool slots
         ``slots`` (batch lives at axis 1 of every cache leaf, after the
         layer-stack axis)."""
         rows_ix = jnp.asarray(rows)

@@ -6,12 +6,14 @@ telemetry included, so queue-depth histograms come for free), and
 :class:`KVCachePool` is the serving engine's slot-per-sequence cache pool
 (moved here from the old monolithic ``serving/engine.py``).
 
-``PROMPT_BUCKETS`` / :func:`bucket_for` implement the padded-prompt-bucket
-scheme: admissions that happen in the same engine tick are batched into
-**one** prefill call whose sequence length is the smallest bucket covering
-the longest prompt in the group, so the number of distinct prefill
-compilations is bounded by the bucket count instead of growing with every
-distinct prompt length seen.
+``PROMPT_BUCKETS`` / :func:`bucket_for` and :func:`row_bucket` implement
+the padded-bucket scheme: admissions that happen in the same engine tick
+are batched into **one** prefill call whose sequence length is the
+smallest bucket covering the longest prompt in the group, over the
+smallest power-of-two row count covering the group (capped at the
+engine's width), so the number of distinct prefill compilations is
+bounded by row buckets x length buckets instead of growing with every
+distinct prompt length and admission size seen.
 """
 
 from __future__ import annotations
@@ -36,6 +38,15 @@ def bucket_for(n: int, buckets: tuple[int, ...]) -> int:
         if b >= n:
             return b
     return n
+
+
+def row_bucket(k: int, width: int) -> int:
+    """Rows a prefill of ``k`` same-tick admissions computes: the next
+    power of two >= ``k``, capped at the engine's ``width`` (1, 2, 4, ...,
+    ``width``: about log2(width) + 1 distinct row counts)."""
+    if not 1 <= k <= width:
+        raise ValueError(f"row count must be in [1, {width}], got {k}")
+    return min(width, 1 << (k - 1).bit_length())
 
 
 class StageQueue:

@@ -18,7 +18,7 @@ from repro.runtime.elastic import ElasticController
 from repro.serving import (ExecutorBackend, PROMPT_BUCKETS, Request, Router,
                            ServingEngine, StageQueue, VirtualBackend,
                            bucket_for, bursty_diurnal_trace, empirical_rate,
-                           mean, percentile, poisson_trace)
+                           mean, percentile, poisson_trace, row_bucket)
 from repro.serving.router import stage_layout
 
 
@@ -138,6 +138,24 @@ class TestStageQueue:
         assert bucket_for(5000, PROMPT_BUCKETS) == 5000   # escape hatch
         with pytest.raises(ValueError):
             bucket_for(0, PROMPT_BUCKETS)
+
+    @pytest.mark.parametrize("width", [1, 5, 8, 16])
+    def test_row_bucket(self, width):
+        got = [row_bucket(k, width) for k in range(1, width + 1)]
+        for k, r in enumerate(got, 1):
+            # covers k, within the width, and the least power of two that
+            # does unless the width caps it
+            assert k <= r <= width
+            assert r == width or (r & (r - 1) == 0 and r // 2 < k)
+        assert got == sorted(got) and got[-1] == width
+        for k in (0, width + 1):
+            with pytest.raises(ValueError):
+                row_bucket(k, width)
+
+    def test_row_bucket_examples(self):
+        assert [row_bucket(k, 16) for k in (1, 2, 3, 5, 9, 16)] == \
+            [1, 2, 4, 8, 16, 16]
+        assert [row_bucket(k, 5) for k in range(1, 6)] == [1, 2, 4, 4, 5]
 
 
 # ---------------------------------------------------------------------------
@@ -356,23 +374,68 @@ def _greedy_reference(model, params, prompt, n_new, max_len=64):
     return toks
 
 
+def _serves_greedy(small_model, lens, width, seed):
+    """Requests of prompt lengths ``lens``, admitted in one tick of a
+    ``width``-slot engine, decode exactly like per-request greedy."""
+    cfg, model, params = small_model
+    rng = np.random.default_rng(seed)
+    prompts = [rng.integers(0, cfg.vocab, n) for n in lens]
+    n_new = 4
+    want = [_greedy_reference(model, params, p, n_new) for p in prompts]
+    eng = ServingEngine(model, params, width=width, max_len=64)
+    assert eng.prompt_buckets is not None      # attn model: auto on
+    for i, p in enumerate(prompts):
+        eng.submit(Request(rid=i, prompt=p, max_new_tokens=n_new))
+    done = sorted(eng.run(), key=lambda r: r.rid)
+    assert len(done) == len(prompts)
+    for r, w in zip(done, want):
+        assert r.tokens == w, (r.rid, r.tokens, w)
+
+
 class TestEnginePlane:
     def test_bucketed_prefill_matches_greedy_mixed_lengths(self, small_model):
         """Same-tick admissions across bucket boundaries (lengths 3..21,
         buckets 16/32/64) must decode exactly like per-request greedy."""
+        _serves_greedy(small_model, (3, 7, 16, 17, 21), width=5, seed=9)
+
+    @pytest.mark.parametrize("lens", [(7,), (3, 17), (5, 16, 21)],
+                             ids=["1row", "2rows", "3rows"])
+    def test_few_rows_of_a_wide_engine_match_greedy(self, small_model,
+                                                    lens):
+        """One, two and three admissions prefill over row buckets 1, 2
+        and 4 of a width-8 engine and decode exactly like greedy."""
+        _serves_greedy(small_model, lens, width=8, seed=13)
+
+    def test_warmup_compiles_every_row_bucket(self, small_model):
+        """After warmup(), an admission of any k up to the width compiles
+        no new prefill program: one per row bucket and length bucket."""
         cfg, model, params = small_model
-        rng = np.random.default_rng(9)
-        prompts = [rng.integers(0, cfg.vocab, n)
-                   for n in (3, 7, 16, 17, 21)]
-        n_new = 4
-        want = [_greedy_reference(model, params, p, n_new) for p in prompts]
-        eng = ServingEngine(model, params, width=5, max_len=64)
-        assert eng.prompt_buckets is not None      # attn model: auto on
-        for i, p in enumerate(prompts):
-            eng.submit(Request(rid=i, prompt=p, max_new_tokens=n_new))
-        done = sorted(eng.run(), key=lambda r: r.rid)
-        for r, w in zip(done, want):
-            assert r.tokens == w, (r.rid, r.tokens, w)
+        rng = np.random.default_rng(14)
+        compiled = []
+
+        def listen(event, secs, fun_name="", **_):
+            if event == "/jax/core/compile/backend_compile_duration" \
+                    and "prefill_step" in fun_name:
+                compiled.append(fun_name)
+
+        def prompt():
+            return rng.integers(0, cfg.vocab, int(rng.integers(2, 18)))
+
+        jax.monitoring.register_event_duration_secs_listener(listen)
+        try:
+            eng = ServingEngine(model, params, width=5, max_len=32)
+            eng.submit(Request(rid=0, prompt=prompt(), max_new_tokens=2))
+            eng.warmup()
+            assert len(compiled) == 4               # rows 1, 2, 4, 5 at 16
+            eng.run()
+            for k in range(1, eng.width + 1):
+                for i in range(k):
+                    eng.submit(Request(rid=i, prompt=prompt(),
+                                       max_new_tokens=2))
+                assert len(eng.run()) == k
+        finally:
+            jax.monitoring.unregister_event_duration_listener(listen)
+        assert len(compiled) == 4
 
     def test_exact_path_still_available(self, small_model):
         cfg, model, params = small_model

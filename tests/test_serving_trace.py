@@ -125,6 +125,24 @@ def test_prefill_counters_are_exact(traced):
     assert sc["stats"] == {"rows": 3}
 
 
+def test_one_row_prefills_one_row(small_model, tmp_path):
+    """A lone admission to a width-4 engine computes a row bucket of one:
+    ``width`` is the rows computed, not the engine's width."""
+    cfg, model, params = small_model
+    eng = ServingEngine(model, params, width=4, max_len=64)
+    (req,) = _requests(cfg, lens=(9,), new=(3,))
+    eng.submit(req)
+    eng.warmup()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        _stepped(eng)
+    finally:
+        jax.profiler.stop_trace()
+    (pre,) = [s["stats"] for s in _spans(str(tmp_path))
+              if s["name"] == "serving.prefill"]
+    assert pre == {"rows": 1, "width": 1, "bucket": 16, "real_tokens": 8}
+
+
 def test_decode_rows_are_the_active_slots(traced):
     _, _, spans, grown, fin = traced
     dec = [s for s in spans if s["name"] == "serving.decode"]
