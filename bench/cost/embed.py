@@ -19,5 +19,5 @@ def token_bytes(model: dict, tokens: int) -> int:
     return ELEM * model["d_model"] * tokens
 
 
-def state_bytes(model: dict, positions: int) -> int:
+def state_bytes(model: dict, cache_lens: list[int]) -> int:
     return 0

@@ -14,5 +14,5 @@ def weight_bytes(model: dict) -> int:
     return ELEM * (3 * model["d_model"] * model["d_ff"] + model["d_model"])
 
 
-def state_bytes(model: dict, positions: int) -> int:
+def state_bytes(model: dict, cache_lens: list[int]) -> int:
     return 0

@@ -15,5 +15,5 @@ def weight_bytes(model: dict) -> int:
     return ELEM * (model["vocab"] * model["d_model"] + model["d_model"])
 
 
-def state_bytes(model: dict, positions: int) -> int:
+def state_bytes(model: dict, cache_lens: list[int]) -> int:
     return 0

@@ -19,8 +19,9 @@ def kind(name: str):
 
 
 def stack(model: dict):
-    """(kind module, times) for every sub-layer of the stack."""
-    n = model["n_layers"]
+    """(kind module, times) for every sub-layer of the stack: each kind of
+    the pattern, which is one group, once a group."""
+    n = model.get("n_groups", model["n_layers"])
     return [(kind(k), n) for k in model["pattern"]]
 
 
@@ -47,11 +48,12 @@ def weight_bytes(model: dict) -> int:
 
 
 def decode_bytes(model: dict, cache_lens: list[int]) -> int:
-    """One decode step: every weight once, the K/V of the filled positions
-    of each active row read, one position of K/V written per row, and the
-    embedding rows of the input tokens."""
+    """One decode step: every weight once, each kind's state for the
+    active rows, whose caches hold ``cache_lens`` positions (for attention
+    the K/V of the filled positions read and one position written a row),
+    and the embedding rows of the input tokens."""
     t = len(cache_lens)
-    state = sum(m.state_bytes(model, sum(cache_lens) + t) * n
+    state = sum(m.state_bytes(model, cache_lens) * n
                 for m, n in stack(model))
     return (weight_bytes(model) + state
             + kind("embed").token_bytes(model, t))

@@ -21,6 +21,7 @@ import gc
 import os
 import shutil
 import time
+from operator import attrgetter
 from types import SimpleNamespace
 
 import numpy as np
@@ -29,30 +30,48 @@ import arrivals
 from compile_clock import CompileClock
 from stats import percentile
 
-# keys of the program's ModelConfig that must equal the configuration's
-# ``model`` section (name in the file, name in the program)
-_SAME = [("n_layers", "n_layers"), ("d_model", "d_model"),
-         ("n_heads", "n_heads"), ("n_kv_heads", "n_kv_heads"),
-         ("head_dim", "head_dim"), ("d_ff", "d_ff"),
-         ("vocab_padded", "vocab"), ("moe_experts", "moe_experts"),
-         ("moe_top_k", "moe_top_k"), ("rope_theta", "rope_theta"),
-         ("activation", "activation")]
-# the llama-style block that the reference implements
+# the program's fields that vary the block, at the values of the plain
+# llama-style block; a field that a reference module's ``CHECKS`` covers is
+# held to the configuration's key in its place
 _PLAIN = {"norm": "rmsnorm", "mlp_gated": True, "use_rope": True,
           "qkv_bias": False, "window": None, "attn_softcap": None,
           "final_softcap": None, "embed_scale": False,
           "post_block_norm": False, "query_pre_attn_scalar": None,
-          "shared_attn_period": 0, "is_encdec": False, "n_img_tokens": 0}
+          "shared_attn_period": 0, "is_encdec": False, "n_img_tokens": 0,
+          "moe_shared_dff": 0}
 
 
 def check_config(cfg, model: dict) -> None:
-    bad = [f"{a}: file {model[a]!r}, program {getattr(cfg, b)!r}"
-           for a, b in _SAME if model[a] != getattr(cfg, b)]
+    """Refuse a program that computes anything else than the reference
+    does for the configuration.  The numbers the reference reads are
+    declared in ``CHECKS`` by ``reference/lm.py`` and by the module of each
+    kind in the pattern: (key of the ``model`` section, the program's field
+    or (field, reading), and the key's default, a value or a function of
+    the section, where the file may leave it out).  Names every key whose
+    value differs from the program's, and every field of the program set
+    away from its plain value that no key covers."""
+    from reference.lm import CHECKS, kind_module
+    decls = CHECKS + [c for k in dict.fromkeys(model["pattern"])
+                      for c in getattr(kind_module(k), "CHECKS", [])]
+    bad, covered = [], {}
+    for key, prog, *default in decls:
+        if key in covered:
+            continue
+        field, read = (prog, attrgetter(prog)) if isinstance(prog, str) \
+            else prog
+        covered[key] = field
+        if key in model:
+            want = model[key]
+        elif default:
+            want = default[0](model) if callable(default[0]) else default[0]
+        else:
+            bad.append(f"{key}: not in the file, program {read(cfg)!r}")
+            continue
+        if want != read(cfg):
+            bad.append(f"{key}: file {want!r}, program {read(cfg)!r}")
     bad += [f"{k}: program {getattr(cfg, k)!r}, reference {v!r}"
-            for k, v in _PLAIN.items() if getattr(cfg, k) != v]
-    if list(cfg.group_kinds) != list(model["pattern"]):
-        bad.append(f"pattern: file {model['pattern']}, program "
-                   f"{list(cfg.group_kinds)}")
+            for k, v in _PLAIN.items()
+            if k not in covered.values() and getattr(cfg, k) != v]
     if cfg.moe_experts:
         from repro.models.moe import pad_experts
         ep = pad_experts(cfg.moe_experts)

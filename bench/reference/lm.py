@@ -1,19 +1,44 @@
-"""Plain reference of a decoder-only LM: token embedding, a stack of groups
-whose sub-layers are the kinds in the configuration's ``pattern`` (each
-kind is a module ``reference/<kind>.py``), a final RMSNorm and the tied
-unembedding.  Float32 throughout (or the float8 control), one sequence at a
-time, one layer's weights in float32 at a time.  It imports nothing of the
-program; it reads the weight arrays that ``bench/weights.py`` drew."""
+"""Plain reference of a decoder-only LM: token embedding, a stack of
+``n_groups`` groups whose sub-layers are the kinds in the configuration's
+``pattern`` (each kind is a module ``reference/<kind>.py``), a final
+RMSNorm and the tied unembedding.  Granite's multipliers sit where
+``granitemoehybrid`` puts them: the embeddings times
+``embedding_multiplier``, each sub-layer's output times
+``residual_multiplier`` before the residual add, the logits divided by
+``logits_scaling`` (each 1 where the configuration leaves it out; the
+attention multiplier is ``reference/attn.py``'s).  Float32 throughout (or
+the float8 control), one sequence at a time, one layer's weights in float32
+at a time.  It imports nothing of the program; it reads the weight arrays
+that ``bench/weights.py`` drew."""
 
 from __future__ import annotations
 
 import importlib
+import math
 from functools import partial
 
 import jax
 import jax.numpy as jnp
 
 from reference.numerics import einsum, rmsnorm
+
+# the keys the stack reads: see ``drivers/lm_serving.py`` ``check_config``;
+# ``embed_scale`` (embeddings times sqrt(d_model)) is the program's
+# embedding multiplier
+CHECKS = [("n_layers", "n_layers"), ("d_model", "d_model"),
+          ("vocab_padded", "vocab"),
+          ("pattern", ("pattern", lambda cfg: list(cfg.group_kinds))),
+          ("n_groups", "n_groups", lambda m: m["n_layers"]),
+          ("embedding_multiplier",
+           ("embed_scale",
+            lambda cfg: math.sqrt(cfg.d_model) if cfg.embed_scale
+            else getattr(cfg, "embedding_multiplier", 1.0)), 1.0),
+          ("residual_multiplier",
+           ("residual_multiplier",
+            lambda cfg: getattr(cfg, "residual_multiplier", 1.0)), 1.0),
+          ("logits_scaling",
+           ("logits_scaling",
+            lambda cfg: getattr(cfg, "logits_scaling", 1.0)), 1.0)]
 
 
 def kind_module(kind: str):
@@ -32,11 +57,13 @@ def sub_names(model: dict) -> list[tuple[str, str]]:
 def hidden(model: dict, w: dict, tokens, mode: str):
     """tokens: (S,) int32 -> final normed hidden states (S, D) float32."""
     x = jnp.take(w["embed"]["table"], tokens, axis=0).astype(jnp.float32)
+    x = x * model.get("embedding_multiplier", 1.0)
+    r = model.get("residual_multiplier", 1.0)
     subs = [(n, kind_module(k)) for n, k in sub_names(model)]
 
     def group(x, wg):
         for n, mod in subs:
-            x = mod.apply(model, wg[n], x, mode)
+            x = x + r * mod.apply(model, wg[n], x, mode)
         return x, None
 
     x, _ = jax.lax.scan(group, x, w["layers"])
@@ -45,8 +72,9 @@ def hidden(model: dict, w: dict, tokens, mode: str):
 
 def logits(model: dict, w: dict, tokens, mode: str):
     """(S, vocab_padded) float32 logits over the tied table."""
-    return einsum("sd,vd->sv", hidden(model, w, tokens, mode),
-                  w["embed"]["table"], mode)
+    out = einsum("sd,vd->sv", hidden(model, w, tokens, mode),
+                 w["embed"]["table"], mode)
+    return out / model.get("logits_scaling", 1.0)
 
 
 @partial(jax.jit, static_argnames=("model_items", "control"))

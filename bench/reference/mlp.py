@@ -10,6 +10,9 @@ import jax
 
 from reference.numerics import einsum, rmsnorm
 
+# the keys this module reads: see ``drivers/lm_serving.py`` ``check_config``
+CHECKS = [("d_ff", "d_ff"), ("activation", "activation")]
+
 
 def init_scale(leaf: str, shape: tuple[int, ...], model: dict) -> float:
     if leaf.endswith("scale"):
@@ -20,8 +23,9 @@ def init_scale(leaf: str, shape: tuple[int, ...], model: dict) -> float:
 
 
 def apply(model: dict, p: dict, x, mode: str):
+    """x: (S, D) float32 residual stream -> the sub-layer's output (S, D)."""
     h = rmsnorm(p["norm"]["scale"], x, model["norm_eps"])
     m = p["mlp"]
     g = jax.nn.silu(einsum("sd,df->sf", h, m["w_gate"], mode))
     u = einsum("sd,df->sf", h, m["w_up"], mode)
-    return x + einsum("sf,fd->sd", g * u, m["w_down"], mode)
+    return einsum("sf,fd->sd", g * u, m["w_down"], mode)

@@ -14,7 +14,7 @@ import run
 import spans
 import tiny
 from metrics import prefill_pad_share, step_idle_ms
-from repro.serving.queues import bucket_for
+from repro.serving.queues import bucket_for, row_bucket
 
 DATA = Path(__file__).parents[1] / "testdata" / "serving-spans.xplane.pb.gz"
 MS = 1e-3
@@ -144,10 +144,11 @@ def test_pad_share_through_run_on_the_cpu(v5e_peaks, monkeypatch):
     buckets = (16, 32, 64, 128)
     assert [a["bucket"] for a in pre] == \
         [bucket_for(max(t), buckets) for t in ticks]
-    assert all(a["width"] == tiny.CONF["width"] for a in pre)
+    # the prefill computes the row bucket of the tick's admissions
+    rows = [row_bucket(len(t), tiny.CONF["width"]) for t in ticks]
+    assert [a["width"] for a in pre] == rows
     assert got["value"] == pytest.approx(100 * (1 - sum(map(sum, ticks)) / (
-        tiny.CONF["width"] * sum(bucket_for(max(t), buckets)
-                                 for t in ticks))))
+        sum(r * bucket_for(max(t), buckets) for r, t in zip(rows, ticks)))))
     dec = [s.args["rows"] for s in ctx.spans if s.name == "serving.decode"]
     assert dec == [len(t["decode"]) for t in ctx.ticks if t["decode"]]
 

@@ -53,3 +53,19 @@ DENSE_CONF = {
     "width": 4, "max_len": 128, "model": DENSE_MODEL,
     "limits": {"token_gap": 0.05},
 }
+
+# the block varied in the ways the reference takes as configuration keys:
+# no positional rotation, a sigmoid-gated shared expert, two layers a group
+VARIANT_MODEL = dict(MODEL, n_layers=4, n_groups=2,
+                     pattern=["attn", "moe", "attn", "moe"], rope=False,
+                     moe_shared_d_ff=48, moe_shared_gate="sigmoid")
+
+VARIANT_CONF = {
+    "name": "tiny-variant", "driver": "lm_serving",
+    "arch": "granite-moe-3b-a800m",
+    "overrides": dict(CONF["overrides"], n_layers=4,
+                      pattern=(("attn", "moe"), ("attn", "moe")),
+                      use_rope=False, moe_shared_dff=48),
+    "width": 4, "max_len": 128, "model": VARIANT_MODEL,
+    "limits": {"token_gap": 0.05},
+}
