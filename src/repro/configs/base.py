@@ -34,6 +34,13 @@ class ModelConfig:
     query_pre_attn_scalar: float | None = None
     embed_scale: bool = False         # gemma: embeddings *= sqrt(d_model)
     post_block_norm: bool = False     # gemma2 extra post-norms
+    # granite: embeddings times embedding_multiplier, each sub-layer's
+    # output times residual_multiplier before its residual add, logits
+    # divided by logits_scaling; at 1.0 nothing is emitted
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    logits_scaling: float = 1.0
+    norm_eps: float = 1e-6            # every RMSNorm's epsilon
 
     # MoE
     moe_experts: int = 0

@@ -1,7 +1,8 @@
 """granite-moe-3b-a800m [moe] — 32L d_model=1536 24H (GQA kv=8) expert
 d_ff=512, 40 routed experts top-8 (padded to 48 for EP sharding), no shared
 expert, vocab=49155 (padded to 49168 for even TP sharding).
-[hf:ibm-granite/granite-3.0-1b-a400m-base; assigned dims used verbatim]"""
+[https://huggingface.co/ibm-granite/granite-3.0-3b-a800m-base/blob/main/
+config.json; assigned dims used verbatim]"""
 
 from repro.models.registry import register
 from .base import ModelConfig

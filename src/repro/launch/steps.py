@@ -46,6 +46,8 @@ def make_prefill_step(model, rules: AxisRules | None, mesh: Mesh | None):
                 kw["frames"] = batch["frames"]
             if "patch_embeds" in batch:
                 kw["patch_embeds"] = batch["patch_embeds"]
+            if "lengths" in batch:
+                kw["lengths"] = batch["lengths"]
             logits, cache = model.prefill(params, batch["tokens"], cache,
                                           **kw)
             return logits, cache

@@ -379,8 +379,12 @@ def embed(p, tokens, scale_by_dim: bool = False):
     return constrain(x, "act_batch", "act_seq", None)
 
 
-def unembed(p, x, softcap: float | None = None):
+def unembed(p, x, softcap: float | None = None, scaling: float = 1.0):
+    """Logits over the tied table, divided by ``scaling`` (granite's
+    ``logits_scaling``; nothing is emitted at 1.0), then soft-capped."""
     logits = jnp.einsum("bsd,vd->bsv", x, p["table"]).astype(jnp.float32)
+    if scaling != 1.0:
+        logits = logits / scaling
     logits = _softcap(logits, softcap)
     # TP layout: batch over data, vocab over model (seq stays unsharded —
     # it is already chunked by the loss and the vocab dim carries the TP).
@@ -392,7 +396,7 @@ def unembed(p, x, softcap: float | None = None):
 # ---------------------------------------------------------------------------
 
 def cross_entropy_loss(embed_params, x, labels, *, softcap=None,
-                       seq_chunk: int | None = None):
+                       seq_chunk: int | None = None, scaling: float = 1.0):
     """x: (B, S, D) final hidden; labels: (B, S) int32; returns mean nll.
 
     ``seq_chunk`` bounds the materialised logits to (B, chunk, V).
@@ -406,7 +410,8 @@ def cross_entropy_loss(embed_params, x, labels, *, softcap=None,
     B, S, D = x.shape
 
     def chunk_loss(xc, yc):
-        logits = unembed(embed_params, xc, softcap=softcap)
+        logits = unembed(embed_params, xc, softcap=softcap,
+                         scaling=scaling)
         logz = jax.nn.logsumexp(logits, axis=-1)
         # gold logit via a vocab-iota mask: the masked sum reduces the
         # *sharded* vocab axis locally + a tiny all-reduce, whereas

@@ -66,11 +66,24 @@ def _sub_spec(cfg, kind: str) -> dict:
 
 
 def _norm(cfg):
-    return L.rmsnorm if cfg.norm == "rmsnorm" else L.layernorm
+    if cfg.norm == "rmsnorm":
+        return partial(L.rmsnorm, eps=cfg.norm_eps)
+    return L.layernorm
 
 
-def apply_sublayer(cfg, kind, p, x, *, positions, cache, cache_len, mode):
-    """Returns (x, new_cache, aux)."""
+def _residual(cfg, x, h):
+    """The residual add, the sub-layer's output ``h`` times
+    ``residual_multiplier`` (granite; nothing is emitted at 1.0)."""
+    if cfg.residual_multiplier != 1.0:
+        h = h * cfg.residual_multiplier
+    return x + h
+
+
+def apply_sublayer(cfg, kind, p, x, *, positions, cache, cache_len, mode,
+                   lengths=None):
+    """Returns (x, new_cache, aux).  ``lengths`` (prefill only): each
+    row's real length; recurrent layers keep the positions at and past it
+    out of their state (attention masks them by ``cache_len`` later)."""
     normf = _norm(cfg)
     aux = jnp.float32(0.0)
 
@@ -85,14 +98,14 @@ def apply_sublayer(cfg, kind, p, x, *, positions, cache, cache_len, mode):
             query_pre_attn_scalar=cfg.query_pre_attn_scalar)
         if cfg.post_block_norm:
             h = normf(p["post_norm"], h)
-        return x + h, new_cache, aux
+        return _residual(cfg, x, h), new_cache, aux
 
     if kind == "mlp":
         h = normf(p["norm"], x)
         h = L.mlp(p["mlp"], h, activation=cfg.activation)
         if cfg.post_block_norm:
             h = normf(p["post_norm"], h)
-        return x + h, None, aux
+        return _residual(cfg, x, h), None, aux
 
     if kind == "moe":
         h = normf(p["norm"], x)
@@ -102,7 +115,7 @@ def apply_sublayer(cfg, kind, p, x, *, positions, cache, cache_len, mode):
                      activation=cfg.activation,
                      group_size=cfg.moe_group_size,
                      impl=cfg.moe_impl)
-        return x + h, None, aux
+        return _residual(cfg, x, h), None, aux
 
     if kind == "mamba2":
         h = normf(p["norm"], x)
@@ -110,10 +123,11 @@ def apply_sublayer(cfg, kind, p, x, *, positions, cache, cache_len, mode):
         h, (ssm_st, conv_st) = mamba2(p["core"], cfg, h,
                                       state=st.get("ssm"),
                                       conv_state=st.get("conv"),
-                                      decode=(mode == "decode"))
+                                      decode=(mode == "decode"),
+                                      lengths=lengths)
         new_cache = None if mode == "train" else \
             {"ssm": ssm_st, "conv": conv_st}
-        return x + h, new_cache, aux
+        return _residual(cfg, x, h), new_cache, aux
 
     if kind == "mlstm":
         h = normf(p["norm"], x)
@@ -123,7 +137,7 @@ def apply_sublayer(cfg, kind, p, x, *, positions, cache, cache_len, mode):
                                   decode=(mode == "decode"))
         new_cache = None if mode == "train" else \
             {"mat": mat, "conv": conv_st}
-        return x + h, new_cache, aux
+        return _residual(cfg, x, h), new_cache, aux
 
     if kind == "slstm":
         st = cache.get("s") if cache else None
@@ -226,14 +240,15 @@ class DecoderLM:
 
     # -- stack ---------------------------------------------------------------
     def _apply_group(self, params_g, shared, x, cache_g, *, positions,
-                     cache_len, mode):
+                     cache_len, mode, lengths=None):
         aux = jnp.float32(0.0)
         new_cache = {}
         for n, k in zip(self.sub_names, self.kinds):
             c = cache_g.get(n) if cache_g else None
             x, nc, a = apply_sublayer(self.cfg, k, params_g[n], x,
                                       positions=positions, cache=c,
-                                      cache_len=cache_len, mode=mode)
+                                      cache_len=cache_len, mode=mode,
+                                      lengths=lengths)
             aux = aux + a
             if nc is not None:
                 new_cache[n] = nc
@@ -245,21 +260,24 @@ class DecoderLM:
                                 rope_theta=self.cfg.rope_theta, causal=True,
                                 kv_cache=c, cache_len=cache_len,
                                 q_chunk=self.cfg.q_chunk)
-            x = x + h
+            x = _residual(self.cfg, x, h)
             h = normf(shared["norm2"], x)
-            x = x + L.mlp(shared["mlp"], h, activation=self.cfg.activation)
+            x = _residual(self.cfg, x, L.mlp(shared["mlp"], h,
+                                             activation=self.cfg.activation))
             if nc is not None:
                 new_cache["shared_attn"] = nc
         return x, new_cache, aux
 
-    def _stack(self, params, x, caches, *, positions, cache_len, mode):
+    def _stack(self, params, x, caches, *, positions, cache_len, mode,
+               lengths=None):
         cfg = self.cfg
         shared = params.get("shared_block")
 
         def body_fn(x, params_g, cache_g):
             return self._apply_group(params_g, shared, x, cache_g,
                                      positions=positions,
-                                     cache_len=cache_len, mode=mode)
+                                     cache_len=cache_len, mode=mode,
+                                     lengths=lengths)
 
         if cfg.remat and mode == "train":
             body_fn = jax.checkpoint(body_fn)
@@ -319,6 +337,8 @@ class DecoderLM:
     def _embed_inputs(self, params, tokens, patch_embeds=None):
         x = L.embed(params["embed"], tokens,
                     scale_by_dim=self.cfg.embed_scale)
+        if self.cfg.embedding_multiplier != 1.0:
+            x = x * self.cfg.embedding_multiplier
         if patch_embeds is not None:
             x = jnp.concatenate([patch_embeds.astype(x.dtype), x], axis=1)
         return constrain(x, "act_batch", "act_seq", None)
@@ -347,21 +367,31 @@ class DecoderLM:
             labels = jnp.concatenate([pad, labels], axis=1)
         nll = L.cross_entropy_loss(params["embed"], hidden, labels,
                                    softcap=self.cfg.final_softcap,
-                                   seq_chunk=self.cfg.loss_seq_chunk)
+                                   seq_chunk=self.cfg.loss_seq_chunk,
+                                   scaling=self.cfg.logits_scaling)
         return nll + 0.01 * aux, {"nll": nll, "aux": aux}
 
-    def prefill(self, params, tokens, cache, patch_embeds=None):
-        """Fill caches with the prompt; returns (last_logits, caches)."""
+    def prefill(self, params, tokens, cache, patch_embeds=None,
+                lengths=None):
+        """Fill caches with the prompt; returns (last_logits, caches).
+
+        ``lengths`` (B,): each row's real length in a right-padded batch;
+        recurrent layers then stop their state at it.  The logits stay
+        those of the last position."""
         x = self._embed_inputs(params, tokens, patch_embeds)
         S = x.shape[1]
         positions = jnp.arange(S, dtype=jnp.int32)[None, :]
         x, caches, _ = self._stack(params, x, cache, positions=positions,
-                                   cache_len=jnp.int32(0), mode="prefill")
+                                   cache_len=jnp.int32(0), mode="prefill",
+                                   lengths=lengths)
         normf = _norm(self.cfg)
         hidden = normf(params["final_norm"], x[:, -1:])
-        logits = L.unembed(params["embed"], hidden,
-                           softcap=self.cfg.final_softcap)
-        return logits, caches
+        return self._logits(params, hidden), caches
+
+    def _logits(self, params, hidden):
+        return L.unembed(params["embed"], hidden,
+                         softcap=self.cfg.final_softcap,
+                         scaling=self.cfg.logits_scaling)
 
     def decode_step(self, params, token, cache, cache_len):
         """token: (B, 1) int32; cache_len: filled length — scalar for
@@ -376,6 +406,4 @@ class DecoderLM:
                                    cache_len=cache_len, mode="decode")
         normf = _norm(self.cfg)
         hidden = normf(params["final_norm"], x)
-        logits = L.unembed(params["embed"], hidden,
-                           softcap=self.cfg.final_softcap)
-        return logits, caches
+        return self._logits(params, hidden), caches

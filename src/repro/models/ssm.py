@@ -50,7 +50,15 @@ def ssd(x, log_a, b, c, *, chunk: int | None = None, initial_state=None,
     Hb, N = b.shape[-2], b.shape[-1]
     shared = Hb == 1
     L = min(chunk, S)
-    assert S % L == 0, (S, L)
+    if S % L:
+        # whole chunks: the tail is padded with no decay and no input, so
+        # the state passes through it unchanged
+        def pad(t):
+            return jnp.pad(t, [(0, 0), (0, L - S % L)]
+                           + [(0, 0)] * (t.ndim - 2))
+        y, final = ssd(pad(x), pad(log_a), pad(b), pad(c), chunk=L,
+                       initial_state=initial_state, unroll=unroll)
+        return y[:, :S], final
     nc = S // L
 
     xc = x.reshape(B, nc, L, H, P).transpose(1, 0, 2, 3, 4)
@@ -161,9 +169,11 @@ def _split_in(cfg, h):
     return z, xbc, dt
 
 
-def _causal_conv(w, bias, x, state=None):
+def _causal_conv(w, bias, x, state=None, lengths=None):
     """Depthwise causal conv1d.  x: (B, S, C); state: (B, K-1, C) or None.
-    Returns (y, new_state)."""
+    Returns (y, new_state): the last K-1 inputs, or with ``lengths`` (B,)
+    each row's last K-1 inputs before its length, ``xp[len:len+K-1]`` of
+    the input behind its state (zeros where the row is shorter)."""
     K = w.shape[0]
     if state is None:
         pad = jnp.zeros((x.shape[0], K - 1, x.shape[2]), x.dtype)
@@ -171,15 +181,33 @@ def _causal_conv(w, bias, x, state=None):
         pad = state.astype(x.dtype)
     xp = jnp.concatenate([pad, x], axis=1)
     y = sum(xp[:, i:i + x.shape[1]] * w[i] for i in range(K))
-    new_state = xp[:, -(K - 1):] if K > 1 else None
+    if K == 1:
+        new_state = None
+    elif lengths is None:
+        new_state = xp[:, -(K - 1):]
+    else:
+        new_state = jax.vmap(lambda r, n: jax.lax.dynamic_slice_in_dim(
+            r, n, K - 1, axis=0))(xp, lengths)
     return jax.nn.silu(y + bias), new_state
 
 
-def mamba2(p, cfg, x, *, state=None, conv_state=None, decode=False):
+def gated_rmsnorm(p, y, z, eps: float):
+    """Mamba-2's gated norm (``MambaRMSNormGated``): ``rmsnorm(y *
+    silu(z))`` over all of d_inner (one group), the gate applied before
+    the norm and in float32."""
+    g = y.astype(jnp.float32) * jax.nn.silu(z.astype(jnp.float32))
+    return rmsnorm(p, g, eps=eps).astype(y.dtype)
+
+
+def mamba2(p, cfg, x, *, state=None, conv_state=None, decode=False,
+           lengths=None):
     """x: (B, S, D) -> (y, (ssm_state, conv_state)).
 
     ``decode=True`` runs the O(1) recurrent update (S == 1 expected);
-    otherwise the chunked SSD scan (training / prefill).
+    otherwise the chunked SSD scan (training / prefill).  ``lengths``
+    (B,), prefill of a right-padded batch: at positions at or past a row's
+    length ``dt`` is 0, so the decay is 1 and nothing enters the state,
+    and both states are those of an exact prefill of the row's length.
     """
     B, S, D = x.shape
     d_inner, H, conv_dim = mamba2_dims(cfg)
@@ -190,12 +218,15 @@ def mamba2(p, cfg, x, *, state=None, conv_state=None, decode=False):
     h = constrain(h, "act_batch", None, "act_ff")
     z, xbc, dt = _split_in(cfg, h)
     xbc, new_conv = _causal_conv(p["conv_w"], p["conv_b"], xbc,
-                                 state=conv_state)
+                                 state=conv_state, lengths=lengths)
     xs, bc = jnp.split(xbc, [d_inner], axis=-1)
     b_in, c_out = jnp.split(bc, 2, axis=-1)             # (B,S,N) each
 
     dt = jax.nn.softplus(dt.astype(jnp.float32)
                          + p["dt_bias"].astype(jnp.float32))  # (B,S,H)
+    if lengths is not None:
+        real = jnp.arange(S)[None, :] < lengths[:, None]
+        dt = jnp.where(real[..., None], dt, 0.0)
     a = -jnp.exp(p["a_log"].astype(jnp.float32))        # (H,) negative
     log_a = dt * a                                      # (B,S,H)
 
@@ -216,8 +247,8 @@ def mamba2(p, cfg, x, *, state=None, conv_state=None, decode=False):
                            unroll=getattr(cfg, "unroll_scans", False))
 
     y = y + xs.reshape(B, S, H, P) * p["d_skip"].astype(xs.dtype)[:, None]
-    y = y.reshape(B, S, d_inner)
-    y = rmsnorm(p["norm"], y) * jax.nn.silu(z)
+    y = gated_rmsnorm(p["norm"], y.reshape(B, S, d_inner), z,
+                      eps=cfg.norm_eps)
     out = jnp.einsum("bsf,fd->bsd", y, p["w_out"])
     return constrain(out, "act_batch", "act_seq", None), (new_state, new_conv)
 

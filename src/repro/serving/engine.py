@@ -31,7 +31,11 @@ the calling thread, with the layer's counters as the spans' arguments
 (event stats in a profiler trace; nothing is formatted when no profiler
 session is active):
 
-* ``serving.step`` (``queued``, ``active`` at entry): one :meth:`step`;
+* ``serving.step`` (``queued``, ``active`` at entry; after admission,
+  what the tick's decode runs over: ``kv_used``, bytes of K/V at the
+  active slots' filled positions, ``state_used``, recurrent-state bytes
+  of the active slots, and ``reserved``, the pool's bytes): one
+  :meth:`step`;
 * ``serving.admit`` (``rows``, ``rids``: space-joined request ids, as a
   comma would split the annotation's arguments): an admission that took
   requests from the queue;
@@ -39,7 +43,9 @@ session is active):
   computed, the row bucket; ``bucket``; ``real_tokens``: the prompt
   positions the rows hold, the rest of ``width x bucket`` being padding
   that the chip computes): dispatch of one prefill;
-* ``serving.scatter`` (``rows``): the prefilled rows' copy into the pool;
+* ``serving.scatter`` (``rows``; ``state_bytes``: the bytes written into
+  the slots, their K/V at every position and their recurrent state):
+  the prefilled rows' copy into the pool;
 * ``serving.decode`` (``rows``: active slots): dispatch of a decode step;
 * ``serving.readback``: the host's wait for the decoded tokens;
 * ``serving.bookkeep`` (``finished``): the per-slot loop after it.
@@ -65,13 +71,14 @@ from .sim import simulate_pipeline_throughput
 __all__ = ["KVCachePool", "Request", "ServingEngine", "ServingStats",
            "simulate_pipeline_throughput"]
 
-# sub-layer kinds whose cache is a recurrent state rather than per-position
-# K/V: a padded prefill would fold the padding into the state irreversibly,
-# so bucketed admission auto-disables for models containing any of these
-# (attention caches are safe: positions beyond a row's length are never
-# visible — the per-row cache_len masks them, and each position is
-# overwritten by the real token before cache_len reaches it)
-RECURRENT_KINDS = frozenset({"mamba2", "mlstm", "slstm"})
+# sub-layer kinds whose recurrent state a padded prefill would fold the
+# padding into, so bucketed admission auto-disables for models containing
+# any of these.  Mamba-2 is not among them: its prefill takes each row's
+# length and keeps the positions past it out of both its states.
+# Attention caches are safe as they are: positions beyond a row's length
+# are never visible — the per-row cache_len masks them, and each position
+# is overwritten by the real token before cache_len reaches it.
+RECURRENT_KINDS = frozenset({"mlstm", "slstm"})
 
 
 class ServingEngine:
@@ -83,10 +90,11 @@ class ServingEngine:
     wins.
 
     ``prompt_buckets`` controls admission batching: ``"auto"`` (default)
-    batches same-tick admissions into one padded-prompt-bucket prefill for
-    attention-cache models and falls back to exact per-request prefill for
-    recurrent-state models (see :data:`RECURRENT_KINDS`); an explicit
-    tuple forces those buckets; ``None`` forces the exact path.
+    batches same-tick admissions into one padded-prompt-bucket prefill,
+    which passes each row's length, for attention and Mamba-2 models
+    (hybrids included), and falls back to exact per-request prefill for
+    xLSTM models (see :data:`RECURRENT_KINDS`); an explicit tuple forces
+    those buckets; ``None`` forces the exact path.
     """
 
     def __init__(self, model, params, *, width: int | None = None,
@@ -160,17 +168,25 @@ class ServingEngine:
                            for k in range(1, self.width + 1)})
             shapes = [(r, b) for b in buckets for r in rows]
         for shape in shapes:
-            self._prefill.lower(self.params, {"tokens": jax.ShapeDtypeStruct(
-                shape, jnp.int32)}).compile()
+            batch = {"tokens": jax.ShapeDtypeStruct(shape, jnp.int32)}
+            if self.prompt_buckets is not None:
+                batch["lengths"] = jax.ShapeDtypeStruct(shape[:1], jnp.int32)
+            self._prefill.lower(self.params, batch).compile()
         return self
 
     def step(self) -> list[Request]:
         """One engine tick: admit what the queue holds and the pool has
         slots for, then one decode step over the active slots.  Returns
         the requests that finished in it."""
+        pool = self.pool
         with TraceAnnotation("serving.step", queued=len(self.queue),
-                             active=len(self.active)):
+                             active=len(self.active)) as span:
             self._admit()
+            if span.is_enabled():      # a profiler records: count bytes
+                span.set_metadata(
+                    kv_used=pool.position_bytes * int(pool.lengths.sum()),
+                    state_used=pool.state_bytes * len(self.active),
+                    reserved=pool.reserved_bytes)
             return self._decode_step() if self.active else []
 
     def run(self, max_steps: int = 10_000) -> list[Request]:
@@ -243,23 +259,25 @@ class ServingEngine:
         from logits identical to an exact prefill's last position (causal
         attention never sees the right padding, and the per-row cache_len
         masks the padded cache positions until real tokens overwrite
-        them)."""
+        them).  The prefill takes each row's length, ``len(prompt) - 1``
+        (0 for padding rows), at which recurrent layers stop their state;
+        it runs even where every length is 0, so that a slot's state is
+        never its previous request's."""
         now = time.perf_counter()
-        pre = max(len(req.prompt) - 1 for req, _ in batch)
-        if pre > 0:
-            bucket = bucket_for(pre, self.prompt_buckets)
-            rows = row_bucket(len(batch), self.width)
-            toks = np.zeros((rows, bucket), np.int32)
-            for j, (req, _) in enumerate(batch):
-                toks[j, :len(req.prompt) - 1] = req.prompt[:-1]
-            with TraceAnnotation(
-                    "serving.prefill", rows=len(batch), width=rows,
-                    bucket=bucket,
-                    real_tokens=sum(len(r.prompt) - 1 for r, _ in batch)):
-                _, cache = self._prefill(self.params,
-                                         {"tokens": jnp.asarray(toks)})
-            self._scatter_rows(cache, rows=list(range(len(batch))),
-                               slots=[slot for _, slot in batch])
+        lens = np.zeros(row_bucket(len(batch), self.width), np.int32)
+        lens[:len(batch)] = [len(req.prompt) - 1 for req, _ in batch]
+        bucket = bucket_for(max(int(lens.max()), 1), self.prompt_buckets)
+        toks = np.zeros((len(lens), bucket), np.int32)
+        for j, (req, _) in enumerate(batch):
+            toks[j, :lens[j]] = req.prompt[:-1]
+        with TraceAnnotation(
+                "serving.prefill", rows=len(batch), width=len(lens),
+                bucket=bucket, real_tokens=int(lens.sum())):
+            _, cache = self._prefill(self.params,
+                                     {"tokens": jnp.asarray(toks),
+                                      "lengths": jnp.asarray(lens)})
+        self._scatter_rows(cache, rows=list(range(len(batch))),
+                           slots=[slot for _, slot in batch])
         for req, slot in batch:
             req.admitted_at = now
             self.pool.lengths[slot] = len(req.prompt) - 1
@@ -277,7 +295,8 @@ class ServingEngine:
         def write(dst, src):
             return dst.at[:, slots_ix].set(src[:, rows_ix])
 
-        with TraceAnnotation("serving.scatter", rows=len(rows)):
+        with TraceAnnotation("serving.scatter", rows=len(rows),
+                             state_bytes=len(rows) * self.pool.slot_bytes):
             self.pool.cache = jax.tree.map(write, self.pool.cache,
                                            src_cache)
 
@@ -288,7 +307,8 @@ class ServingEngine:
             # scalar-state tuples where it is axis 1 as well.
             return dst.at[:, slot:slot + 1].set(src)
 
-        with TraceAnnotation("serving.scatter", rows=1):
+        with TraceAnnotation("serving.scatter", rows=1,
+                             state_bytes=self.pool.slot_bytes):
             self.pool.cache = jax.tree.map(write, self.pool.cache,
                                            single_cache)
 

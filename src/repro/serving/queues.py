@@ -21,6 +21,7 @@ from __future__ import annotations
 from collections import Counter, deque
 from typing import Any
 
+import jax
 import numpy as np
 
 # small fixed set: at most len(PROMPT_BUCKETS) prefill compiles per engine,
@@ -96,19 +97,42 @@ class StageQueue:
         return self._q.popleft()
 
 
+def slot_sizes(cache, width: int, max_len: int) -> tuple[int, int]:
+    """(bytes of one slot's recurrent state, bytes of one position of its
+    K/V) in a pool cache tree of ``width`` slots: leaves named ``k`` or
+    ``v`` hold K/V for ``max_len`` positions, every other leaf a state
+    whose size does not depend on the length."""
+    state = position = 0
+    for path, leaf in jax.tree_util.tree_leaves_with_path(cache):
+        if getattr(path[-1], "key", None) in ("k", "v"):
+            position += leaf.nbytes // (width * max_len)
+        else:
+            state += leaf.nbytes // width
+    return state, position
+
+
 class KVCachePool:
     """Fixed-width slot pool over the stacked cache pytree.
 
-    Slot i owns batch row i of every cache leaf.  Freeing a slot just
-    recycles the row (lengths are tracked per slot) — sequence-granularity
-    paging, the memory-management layer a vLLM-style block table would
-    refine further.
+    Slot i owns batch row i of every cache leaf: K/V of attention layers
+    and the recurrent state of Mamba-2 / xLSTM layers side by side.
+    Freeing a slot just recycles the row (lengths are tracked per slot) —
+    sequence-granularity paging, the memory-management layer a vLLM-style
+    block table would refine further.  ``state_bytes`` and
+    ``position_bytes`` (:func:`slot_sizes`), ``reserved_bytes`` (the
+    whole tree) and ``slot_bytes`` (one slot's row of it) size what the
+    slots hold and reserve.
     """
 
     def __init__(self, model, width: int, max_len: int):
         self.width = width
         self.max_len = max_len
         self.cache = model.init_cache(batch=width, max_len=max_len)
+        self.state_bytes, self.position_bytes = slot_sizes(
+            self.cache, width, max_len)
+        self.reserved_bytes = sum(x.nbytes for x in
+                                  jax.tree.leaves(self.cache))
+        self.slot_bytes = self.reserved_bytes // width
         self.lengths = np.zeros(width, np.int32)
         self.free = deque(range(width))
         self.slot_req: dict[int, int] = {}

@@ -23,6 +23,9 @@ PARENT = {"serving.step": None, "serving.admit": "serving.step",
           "serving.decode": "serving.step",
           "serving.readback": "serving.step",
           "serving.bookkeep": "serving.step"}
+# bytes of K/V a position of a slot of ``small_model``: two layers, K and
+# V, two heads of 16, bfloat16
+POSITION = 2 * 2 * 2 * 16 * 2
 
 
 @pytest.fixture(scope="module")
@@ -103,7 +106,11 @@ def test_every_span_appears_and_nests(traced):
     assert len(steps) == len(grown)
     # one admission, in the first step: the queue's three requests
     first = steps[0]
-    assert first["stats"] == {"queued": 3, "active": 0}
+    # after admission the pool holds the three prompts less their last
+    # tokens, of a width-4 pool of 64 positions a slot and no state
+    assert first["stats"] == {"queued": 3, "active": 0,
+                              "kv_used": POSITION * (4 + 8 + 19),
+                              "state_used": 0, "reserved": 4 * 64 * POSITION}
     assert all(s["stats"]["queued"] == 0 for s in steps[1:])
     admits = [s for s in spans if s["name"] == "serving.admit"]
     assert len(admits) == 1
@@ -122,7 +129,7 @@ def test_prefill_counters_are_exact(traced):
         "real_tokens": sum(n - 1 for n in lens)}
     assert pre["stats"]["bucket"] == 32
     (sc,) = [s for s in spans if s["name"] == "serving.scatter"]
-    assert sc["stats"] == {"rows": 3}
+    assert sc["stats"] == {"rows": 3, "state_bytes": 3 * 64 * POSITION}
 
 
 def test_one_row_prefills_one_row(small_model, tmp_path):
@@ -177,7 +184,7 @@ def test_each_exact_prefill_is_a_span(small_model, tmp_path):
     assert pre == [{"rows": 1, "width": 1, "bucket": len(r.prompt),
                     "real_tokens": len(r.prompt)} for r in reqs]
     assert [s["stats"] for s in spans if s["name"] == "serving.scatter"] \
-        == [{"rows": 1}] * 3
+        == [{"rows": 1, "state_bytes": 64 * POSITION}] * 3
     for s in spans:
         assert s["parent"] == PARENT[s["name"]], s
 
