@@ -203,10 +203,17 @@ def sdpa(q, k, v, *, q_pos, k_pos, causal=True, window=None, softcap=None,
     path then chunks at the same granularity the cost model priced — and
     to 512 otherwise.
 
-    GQA is handled by repeating K/V to H heads: the repeated dim then shards
-    cleanly over the TP axis, whereas a grouped (Hk, G) einsum forces XLA
-    into involuntary resharding (observed: replicated (B,Hk,G,C,Sk) score
-    tensors blowing past HBM on starcoder2/internvl2 — EXPERIMENTS.md §Perf).
+    A single-position query (``Sq == 1``, the decode step) is contracted
+    grouped by KV head: q is viewed as (B, 1, Hk, G, hd) and meets K/V as
+    stored, so no G-fold copy of the cache is made.  Head h reads KV head
+    h // G, the order ``jnp.repeat`` gives.  Its scores are only
+    (B, Hk, G, 1, Sk), small enough that how they shard does not matter.
+
+    A multi-position query repeats K/V to H heads instead: the repeated dim
+    then shards cleanly over the TP axis, whereas a grouped (Hk, G) einsum
+    forces XLA into involuntary resharding (observed: replicated
+    (B,Hk,G,C,Sk) score tensors blowing past HBM on starcoder2/internvl2 at
+    long chunked prefill — EXPERIMENTS.md §Perf).
     """
     if q_chunk is None:
         from repro.kernels.substrate import serving_param
@@ -215,6 +222,12 @@ def sdpa(q, k, v, *, q_pos, k_pos, causal=True, window=None, softcap=None,
     Hk = k.shape[2]
     G = H // Hk
     scale = 1.0 / math.sqrt(hd)
+
+    if Sq == 1:
+        return _sdpa_grouped_single(q, k, v, q_pos=q_pos, k_pos=k_pos,
+                                    causal=causal, window=window,
+                                    softcap=softcap, k_len_valid=k_len_valid,
+                                    scale=scale)
 
     if G > 1:
         k = jnp.repeat(k, G, axis=2)        # (B, Sk, H, hd)
@@ -253,6 +266,30 @@ def sdpa(q, k, v, *, q_pos, k_pos, causal=True, window=None, softcap=None,
     # (B,Hk,G,chunk,Sk) probability residuals of every chunk
     _, outs = jax.lax.scan(jax.checkpoint(body), None, (qs, ps))
     return outs.transpose(1, 0, 2, 3, 4).reshape(B, Sq, H, hd)
+
+
+def _sdpa_grouped_single(q, k, v, *, q_pos, k_pos, causal, window, softcap,
+                         k_len_valid, scale):
+    """``sdpa`` for one query position, contracted grouped by KV head.
+
+    Same float32 arithmetic as the repeat path: bf16 K/V are widened inside
+    the contractions, and HIGHEST precision keeps an MXU lowering from
+    rounding any float32 operand to bf16."""
+    B, _, H, hd = q.shape
+    Hk = k.shape[2]
+    G = H // Hk
+    hi = jax.lax.Precision.HIGHEST
+    qg = q.reshape(B, 1, Hk, G, hd).astype(jnp.float32)
+    s = jnp.einsum("bckgd,bskd->bkgcs", qg, k.astype(jnp.float32),
+                   precision=hi) * scale
+    s = _softcap(s, softcap)
+    m = _attn_mask(q_pos, k_pos, causal=causal, window=window,
+                   k_len_valid=k_len_valid)
+    s = jnp.where(m[:, None, None] if m.ndim == 3 else m, s, -1e30)
+    p = jax.nn.softmax(s, axis=-1)
+    o = jnp.einsum("bkgcs,bskd->bckgd", p, v.astype(jnp.float32),
+                   precision=hi)
+    return o.reshape(B, 1, H, hd).astype(q.dtype)
 
 
 def attention(p, x, *, positions, rope_theta=10000.0, causal=True,
